@@ -3,16 +3,18 @@
 Per kernel (``spmm_tiled``, ``spmm_ata``, ``kmeans_update``) at its bench
 shape: measured wall time next to the *analytic* FLOPs and minimum HBM
 bytes of the launch, reduced to achieved FLOP/s and bytes/s against the
-TPU v5e peaks (``launch/roofline.py`` HW constants). This states every
-kernel win against the hardware ceiling instead of the previous run: the
+chip's published peaks (``launch/roofline.HW``, keyed by device kind).
+This states every kernel win against the hardware ceiling instead of
+the previous run: the
 ``us`` column tracks regressions, the ``pk`` fractions say how much
 headroom is even left to chase, and the ``ai`` (arithmetic intensity,
 FLOPs/byte) column says which wall — 240 FLOP/B is the v5e ridge — the
 kernel lives under.
 
 Off-TPU the kernels dispatch to their jnp tile-reference tier, so the
-achieved numbers are the CPU production path's; the analytic FLOPs/bytes
-columns are backend-independent. Row contract (benchmarks/run.py):
+achieved numbers are the CPU production path's and the peak fractions
+read ``not_measured``; the analytic FLOPs/bytes columns are
+backend-independent. Row contract (benchmarks/run.py):
 ``roofline_<kernel>,us_per_call,derived``.
 """
 
@@ -36,17 +38,25 @@ def _time(fn, *args) -> float:
 
 def _row(report, name: str, secs: float, flops: float, bytes_: float,
          extra: str) -> None:
-    from repro.launch.roofline import HW
+    import jax
+
+    from repro.launch.roofline import peaks
 
     ach_flops = flops / secs
     ach_bw = bytes_ / secs
     ai = flops / bytes_ if bytes_ else 0.0
+    if jax.default_backend() == "tpu":
+        hw = peaks(jax.devices()[0].device_kind)
+        pk = (f"pk_flops={ach_flops / hw['flops_bf16']:.2e} "
+              f"pk_hbm={ach_bw / hw['hbm_bw']:.2e}")
+    else:
+        # a CPU time divided by a chip's peak is no roofline share
+        pk = "pk=not_measured"
     report(
         f"roofline_{name},{secs * 1e6:.0f},"
         f"flops={flops:.3g} bytes={bytes_:.3g} ai={ai:.1f} "
         f"ach_gflops={ach_flops / 1e9:.1f} ach_gbps={ach_bw / 1e9:.1f} "
-        f"pk_flops={ach_flops / HW['flops_bf16']:.2e} "
-        f"pk_hbm={ach_bw / HW['hbm_bw']:.2e} {extra}")
+        f"{pk} {extra}")
 
 
 def _bcoo(rng, m: int, n: int, d: float):

@@ -74,14 +74,14 @@ def _drive(model, payloads, *, axis: str, k: int, replicas: int,
         max_queue_rows=sum(p.shape[0] for p in payloads) + batch)
     with streaming.AssignService(model, version="v1", config=cfg,
                                  metrics=reg) as svc:
-        svc.submit(payloads[0], axis=axis, k=k).result(timeout=120.0)
+        warm = svc.submit(payloads[0], axis=axis, k=k).result(timeout=120.0)
         t0 = time.perf_counter()
         tickets = [svc.submit(x, axis=axis, k=k) for x in payloads]
         results = [t.result(timeout=120.0) for t in tickets]
         wall = time.perf_counter() - t0
         stats = svc.stats()
     rows = sum(len(r.labels) for r in results if r.ok)
-    errors = sum(not r.ok for r in results)
+    errors = sum(not r.ok for r in [warm, *results])
     return {
         "rows": rows, "errors": errors,
         "qps": rows / max(wall, 1e-9),
@@ -113,8 +113,8 @@ def _swap_under_load(model, model2, *, batch: int, n_requests: int) -> dict:
 
     with streaming.AssignService(model, version="v1", config=cfg,
                                  metrics=reg) as svc:
-        warm = np.zeros((size, dim), np.float32)
-        svc.submit(warm).result(timeout=120.0)
+        warm = svc.submit(np.zeros((size, dim), np.float32)).result(
+            timeout=120.0)
 
         def pump(seed: int) -> None:
             rng = np.random.default_rng(seed)
@@ -146,7 +146,7 @@ def _swap_under_load(model, model2, *, batch: int, n_requests: int) -> dict:
         stats = svc.stats()
 
     rows = sum(len(r.labels) for r in results if r.ok)
-    errors = sum(not r.ok for r in results)
+    errors = sum(not r.ok for r in [warm, *results])
     return {
         "rows": rows, "errors": errors,
         "qps": rows / max(wall, 1e-9),

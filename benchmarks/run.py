@@ -116,6 +116,10 @@ def main(argv=None) -> None:
             print(name)
         return
 
+    from repro.runtime import compile_cache
+
+    compile_cache.enable()
+
     rows: dict[str, float] = {}
 
     def report(line: str) -> None:

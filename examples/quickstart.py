@@ -20,9 +20,11 @@ from repro.core import LAMCConfig, lamc_cocluster, cocluster_scores
 from repro.core.baselines import scc_full
 from repro.core.metrics import nmi
 from repro.data import planted_cocluster_matrix
+from repro.runtime import compile_cache
 
 
 def main():
+    compile_cache.enable()
     obs.configure(enabled=True)  # span-trace the whole loop (DESIGN.md §14)
     obs.reset_trace()
     rng = np.random.default_rng(0)

@@ -40,13 +40,9 @@ import logging
 from typing import Callable, Iterator
 
 import jax
+from jax.extend import core as _jcore
 
 from .findings import Finding
-
-try:  # jax.core is the semi-public home through 0.4.x
-    from jax import core as _jcore
-except ImportError:  # pragma: no cover
-    from jax._src import core as _jcore
 
 __all__ = ["audit_rng_gather", "audit_dtypes", "count_recompiles",
            "audit_entry_jaxpr", "RNG_SOURCES", "BARRIERS"]
@@ -211,8 +207,7 @@ class _TaintWalker:
             else:
                 out_t = [any(in_t)] * len(eqn.outvars)
             for v, t in zip(eqn.outvars, out_t):
-                if not isinstance(v, _jcore.DropVar):
-                    taint[v] = t
+                taint[v] = t
         return [is_t(v) for v in jaxpr.outvars]
 
 

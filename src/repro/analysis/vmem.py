@@ -139,8 +139,8 @@ def ata_resident_bytes(n_tile_rows: int, n_tile_cols: int, bm: int, bk: int,
 
     ``with_gram`` adds the ``(bn, bn)`` Gram output of the fused
     subspace-iteration step; ``scaled`` adds the per-payload row/col
-    scale slivers (``(1, bm)`` + ``(1, bk)``, priced at their padded
-    sublane granule)."""
+    scale slivers (``(1, 1, bm)`` + ``(1, 1, bk)`` blocks, priced at
+    their padded sublane granule)."""
     total = (n_tile_rows * bm + n_tile_cols * bk) * bn * itemsize
     if with_gram:
         total += bn * bn * itemsize
@@ -152,8 +152,8 @@ def ata_resident_bytes(n_tile_rows: int, n_tile_cols: int, bm: int, bk: int,
 
 def _scale_blocks(bm: int, bk: int) -> list[BlockUse]:
     return [
-        BlockUse("row_scale", (1, bm)),
-        BlockUse("col_scale", (1, bk)),
+        BlockUse("row_scale", (1, 1, bm)),
+        BlockUse("col_scale", (1, 1, bk)),
     ]
 
 
@@ -193,16 +193,16 @@ KERNEL_SPECS: dict[str, Callable[[], KernelEstimate]] = {
     "kmeans_assign": lambda: estimate_kernel("kmeans_assign", [
         BlockUse("x", (512, 1024), array_shape=(4096, 1024)),
         BlockUse("centroids", (512, 1024), array_shape=(512, 1024)),
-        BlockUse("labels", (512,), dtype="int32", array_shape=(4096,)),
-        BlockUse("d2", (512,), array_shape=(4096,)),
+        BlockUse("labels", (1, 512), dtype="int32", array_shape=(1, 4096)),
+        BlockUse("d2", (1, 512), array_shape=(1, 4096)),
     ]),
     # ops.kmeans_update adds the (K, D) sums and (1, K) counts accumulators
     "kmeans_update": lambda: estimate_kernel("kmeans_update", [
         BlockUse("x", (512, 1024), array_shape=(4096, 1024)),
         BlockUse("centroids", (512, 1024), array_shape=(512, 1024)),
-        BlockUse("weights", (512,), array_shape=(4096,)),
-        BlockUse("labels", (512,), dtype="int32", array_shape=(4096,)),
-        BlockUse("d2", (512,), array_shape=(4096,)),
+        BlockUse("weights", (1, 512), array_shape=(1, 4096)),
+        BlockUse("labels", (1, 512), dtype="int32", array_shape=(1, 4096)),
+        BlockUse("d2", (1, 512), array_shape=(1, 4096)),
         BlockUse("sums", (512, 1024), array_shape=(512, 1024)),
         BlockUse("counts", (1, 512), array_shape=(1, 512)),
     ]),
@@ -210,8 +210,8 @@ KERNEL_SPECS: dict[str, Callable[[], KernelEstimate]] = {
     "cosine_assign": lambda: estimate_kernel("cosine_assign", [
         BlockUse("x", (512, 1024), array_shape=(4096, 1024)),
         BlockUse("signatures", (1024, 1024), array_shape=(1024, 1024)),
-        BlockUse("labels", (512,), dtype="int32", array_shape=(4096,)),
-        BlockUse("score", (512,), array_shape=(4096,)),
+        BlockUse("labels", (1, 512), dtype="int32", array_shape=(1, 4096)),
+        BlockUse("score", (1, 512), array_shape=(1, 4096)),
     ]),
     "cosine_topk": lambda: estimate_kernel("cosine_topk", [
         BlockUse("x", (512, 1024), array_shape=(4096, 1024)),
@@ -222,8 +222,8 @@ KERNEL_SPECS: dict[str, Callable[[], KernelEstimate]] = {
     # kernels.bipartite_normalize at its default 256x256 tiles
     "scale_apply": lambda: estimate_kernel("scale_apply", [
         BlockUse("a", (256, 256), array_shape=(4096, 4096)),
-        BlockUse("d1", (256,), array_shape=(4096,)),
-        BlockUse("d2", (256,), array_shape=(4096,)),
+        BlockUse("d1", (1, 256), array_shape=(1, 4096)),
+        BlockUse("d2", (1, 256), array_shape=(1, 4096)),
         BlockUse("out", (256, 256), array_shape=(4096, 4096)),
     ]),
     # flash attention: tile_q=512, tile_k=512, head dim 128 + m/l/acc scratch

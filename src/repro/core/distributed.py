@@ -30,8 +30,8 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from .. import obs
@@ -66,6 +66,19 @@ def _merge_votes_local(point_global, index_of_points, n_points, k_global):
     return votes.at[index_of_points.reshape(-1), point_global.reshape(-1)].add(1.0)
 
 
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis in Auto mode.
+
+    ``jax.make_mesh`` builds Explicit axes by default; the block scatter
+    relies on GSPMD to place the gathered stack, and
+    ``with_sharding_constraint`` only accepts Auto axes.
+    """
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def lamc_step_fn(cfg: LAMCConfig, plan: partition.PartitionPlan,
                  mesh: Mesh, block_axes: Sequence[str],
                  resample_axis: str | None = None):
@@ -82,6 +95,7 @@ def lamc_step_fn(cfg: LAMCConfig, plan: partition.PartitionPlan,
     Requires ``plan.t_p %% mesh.shape[resample_axis] == 0``.
     Returns ``(step, in_shardings, out_shardings)``.
     """
+    mesh = _auto_axes(mesh)
     n_dev = 1
     for ax in block_axes:
         n_dev *= mesh.shape[ax]
@@ -125,7 +139,7 @@ def lamc_step_fn(cfg: LAMCConfig, plan: partition.PartitionPlan,
         in_specs=(block_spec, P(axes), block_spec, block_spec),
         out_specs=(P(axes, None), P(axes, None), block_spec, P(axes, None),
                    block_spec, P(axes, None)),
-        check_rep=False,
+        check_vma=False,
     )
 
     def local_atom_phase_tp(blocks, keys, row_feats, col_feats):
@@ -141,7 +155,7 @@ def lamc_step_fn(cfg: LAMCConfig, plan: partition.PartitionPlan,
         in_specs=(tp_block, P(ra, axes), tp_block, tp_block),
         out_specs=(P(ra, axes, None), P(ra, axes, None), tp_block,
                    P(ra, axes, None), tp_block, P(ra, axes, None)),
-        check_rep=False,
+        check_vma=False,
     ) if ra is not None else None
 
     def merge_phase(row_sigs, row_counts, row_labels, row_pos,
@@ -229,7 +243,7 @@ def lamc_step_fn(cfg: LAMCConfig, plan: partition.PartitionPlan,
                   P(tdim, axes, None, None), tblock, P(tdim, axes, None), tblock,
                   rep),
         out_specs=(rep, rep),
-        check_rep=False,
+        check_vma=False,
     )
 
     def step(a):
@@ -363,7 +377,7 @@ def distributed_lamc(mesh: Mesh, a: jax.Array, cfg: LAMCConfig,
         # XLA program; one fenced span covers the lot (DESIGN.md §14).
         with obs.span("pipeline",
                       phases="scatter->atom->merge") as ps:
-            with mesh:
+            with in_sh.mesh:          # lamc_step_fn's Auto view of mesh
                 out = ps.fence(step_c(a))
         with obs.span("finalize") as fs:
             return fs.fence(LAMCResult(

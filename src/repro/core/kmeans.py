@@ -22,6 +22,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as _kops
+
 __all__ = ["KMeansResult", "assign", "kmeans", "kmeanspp_init"]
 
 
@@ -43,14 +45,10 @@ def assign(x: jax.Array, centroids: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 
 def _pallas_assign(x, centroids):
-    from repro.kernels import ops as _kops  # lazy: kernels are optional on CPU
-
     return _kops.kmeans_assign(x, centroids)
 
 
 def _pallas_update(x, centroids, weights):
-    from repro.kernels import ops as _kops  # lazy: kernels are optional on CPU
-
     return _kops.kmeans_update(x, centroids, weights=weights)
 
 

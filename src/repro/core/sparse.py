@@ -28,6 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import sparse as jsparse
 
+from repro.kernels.spmm import BlockSparseMatrix
+
 __all__ = [
     "is_bcoo",
     "validate_bcoo",
@@ -278,10 +280,6 @@ def ell_scale_rows_cols(a: EllOperator, s1: jax.Array,
 
 def is_tiled(a) -> bool:
     """True if ``a`` is a ``kernels.spmm.BlockSparseMatrix`` operand."""
-    try:
-        from repro.kernels.spmm import BlockSparseMatrix
-    except ImportError:  # kernels unavailable (minimal install)
-        return False
     return isinstance(a, BlockSparseMatrix)
 
 

@@ -34,6 +34,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as _kops
+
 from . import kmeans as _kmeans
 from . import sparse as _sparse
 
@@ -149,8 +151,6 @@ def randomized_svd(key: jax.Array, a: jax.Array, rank: int, n_iter: int = 4,
         rmatvec = lambda x: _sparse.ell_rmatvec(a, x)
         ata = ata_step = None
     elif _sparse.is_tiled(a):
-        from repro.kernels import ops as _kops  # lazy: kernels optional on CPU
-
         matvec = lambda x: _kops.spmm_tiled(a, x)
         rmatvec = lambda x: _kops.spmm_tiled(a, x, transpose=True)
         ata = lambda x: _kops.spmm_ata(a, x)
@@ -164,8 +164,6 @@ def randomized_svd(key: jax.Array, a: jax.Array, rank: int, n_iter: int = 4,
         else:
             ata_step = lambda x: orth(ata(x))
     elif _sparse.is_bcoo(a):
-        from repro.kernels import ops as _kops
-
         matvec = lambda x: _kops.spmm(a, x)                  # A @ x
         rmatvec = lambda x: _kops.spmm(a, x, transpose=True)  # A.T @ x
         ata = ata_step = None

@@ -9,6 +9,8 @@ XLA already fuses well; they stay in jnp (see ops.bipartite_normalize).
 
 Grid: 2-D over (row tiles, col tiles). VMEM per step:
 ``tile_m*tile_n + tile_m + tile_n`` floats — 256 KB at 256 x 256 f32.
+Degrees ride in as lane-dense ``(1, M)`` / ``(1, N)`` rows: the TPU
+compiler refuses a ``(tile_m,)`` block of a 1-D array.
 """
 
 from __future__ import annotations
@@ -24,18 +26,18 @@ __all__ = ["scale_apply_pallas"]
 
 def _kernel(a_ref, d1_ref, d2_ref, out_ref, *, eps: float):
     a = a_ref[...].astype(jnp.float32)                 # (TM, TN)
-    d1 = d1_ref[...].astype(jnp.float32)               # (TM,)
-    d2 = d2_ref[...].astype(jnp.float32)               # (TN,)
-    s1 = jax.lax.rsqrt(jnp.maximum(d1, eps))
+    d1 = d1_ref[...].astype(jnp.float32)               # (1, TM)
+    d2 = d2_ref[...].astype(jnp.float32)               # (1, TN)
+    s1 = jax.lax.rsqrt(jnp.maximum(d1, eps)).reshape(a.shape[0], 1)
     s2 = jax.lax.rsqrt(jnp.maximum(d2, eps))
-    out_ref[...] = (a * s1[:, None] * s2[None, :]).astype(out_ref.dtype)
+    out_ref[...] = (a * s1 * s2).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_n", "eps", "interpret"))
 def scale_apply_pallas(
     a: jax.Array,    # (M, N)
-    d1: jax.Array,   # (M,) raw row degrees
-    d2: jax.Array,   # (N,) raw col degrees
+    d1: jax.Array,   # (1, M) raw row degrees
+    d2: jax.Array,   # (1, N) raw col degrees
     tile_m: int = 256,
     tile_n: int = 256,
     eps: float = 1e-8,
@@ -48,8 +50,8 @@ def scale_apply_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j)),
-            pl.BlockSpec((tile_m,), lambda i, j: (i,)),
-            pl.BlockSpec((tile_n,), lambda i, j: (j,)),
+            pl.BlockSpec((1, tile_m), lambda i, j: (0, i)),
+            pl.BlockSpec((1, tile_n), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),

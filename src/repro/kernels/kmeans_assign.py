@@ -26,6 +26,11 @@ VMEM budget per grid step: ``tile_p*D + K*D + tile_p*K`` floats — e.g.
 
 Grid: ``(ceil(P / tile_p),)`` — 1-D over point tiles; centroids are
 broadcast to every step (index_map returns block 0).
+
+Per-point outputs are lane-dense ``(1, P)`` rows written in
+``(1, tile_p)`` blocks: the TPU compiler tiles a 1-D ``(P,)`` array by
+1024 while a ``(tile_p,)`` block asks for ``tile_p``, and refuses the
+mismatch. ``ops.py`` takes row 0.
 """
 
 from __future__ import annotations
@@ -51,8 +56,9 @@ def _kernel(x_ref, c_ref, labels_ref, d2_ref):
         preferred_element_type=jnp.float32,
     )                                                # (TP, K) on the MXU
     d2 = x2 - 2.0 * xc + c2[None, :]
-    labels_ref[...] = jnp.argmin(d2, axis=-1).astype(jnp.int32)
-    d2_ref[...] = jnp.maximum(jnp.min(d2, axis=-1), 0.0)
+    tp = d2.shape[0]
+    labels_ref[...] = jnp.argmin(d2, axis=-1).astype(jnp.int32).reshape(1, tp)
+    d2_ref[...] = jnp.maximum(jnp.min(d2, axis=-1), 0.0).reshape(1, tp)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_p", "interpret"))
@@ -62,8 +68,9 @@ def kmeans_assign_pallas(
     tile_p: int = 512,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Raw kernel invocation. Use ``repro.kernels.ops.kmeans_assign`` for the
-    shape-safe public wrapper (padding, sentinel handling, CPU fallback)."""
+    """Raw kernel invocation; returns ``(labels, d2)`` shaped ``(1, P)``.
+    Use ``repro.kernels.ops.kmeans_assign`` for the shape-safe public
+    wrapper (padding, sentinel handling, CPU fallback)."""
     p, d = x.shape
     k, _ = centroids.shape
     grid = (pl.cdiv(p, tile_p),)
@@ -75,12 +82,12 @@ def kmeans_assign_pallas(
             pl.BlockSpec((k, d), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_p,), lambda i: (i,)),
-            pl.BlockSpec((tile_p,), lambda i: (i,)),
+            pl.BlockSpec((1, tile_p), lambda i: (0, i)),
+            pl.BlockSpec((1, tile_p), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((p,), jnp.int32),
-            jax.ShapeDtypeStruct((p,), jnp.float32),
+            jax.ShapeDtypeStruct((1, p), jnp.int32),
+            jax.ShapeDtypeStruct((1, p), jnp.float32),
         ],
         interpret=interpret,
     )(x, centroids)
@@ -98,8 +105,9 @@ def _cosine_kernel(k_valid, x_ref, s_ref, labels_ref, score_ref):
     # beat any all-negative real row — force them unselectable instead
     valid = jax.lax.broadcasted_iota(jnp.int32, xs.shape, 1) < k_valid
     xs = jnp.where(valid, xs, -jnp.inf)
-    labels_ref[...] = jnp.argmax(xs, axis=-1).astype(jnp.int32)
-    score_ref[...] = jnp.max(xs, axis=-1)
+    tp = xs.shape[0]
+    labels_ref[...] = jnp.argmax(xs, axis=-1).astype(jnp.int32).reshape(1, tp)
+    score_ref[...] = jnp.max(xs, axis=-1).reshape(1, tp)
 
 
 def _cosine_topk_kernel(k_valid, k_top, x_ref, s_ref, labels_ref, score_ref):
@@ -172,8 +180,9 @@ def cosine_assign_pallas(
     tile_p: int = 512,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Raw kernel invocation. Use ``repro.kernels.ops.cosine_assign`` for
-    the shape-safe public wrapper (padding, CPU fallback)."""
+    """Raw kernel invocation; returns ``(labels, score)`` shaped
+    ``(1, P)``. Use ``repro.kernels.ops.cosine_assign`` for the
+    shape-safe public wrapper (padding, CPU fallback)."""
     p, d = x.shape
     k, _ = signatures.shape
     grid = (pl.cdiv(p, tile_p),)
@@ -185,12 +194,12 @@ def cosine_assign_pallas(
             pl.BlockSpec((k, d), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_p,), lambda i: (i,)),
-            pl.BlockSpec((tile_p,), lambda i: (i,)),
+            pl.BlockSpec((1, tile_p), lambda i: (0, i)),
+            pl.BlockSpec((1, tile_p), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((p,), jnp.int32),
-            jax.ShapeDtypeStruct((p,), jnp.float32),
+            jax.ShapeDtypeStruct((1, p), jnp.int32),
+            jax.ShapeDtypeStruct((1, p), jnp.float32),
         ],
         interpret=interpret,
     )(x, signatures)

@@ -27,6 +27,10 @@ of a v5e, leaving headroom for double-buffering.
 Grid: ``(ceil(P / tile_p),)`` — sequential on TPU, so the accumulator
 blocks (index_map pinned to block 0) carry across steps; step 0 zeroes
 them via ``pl.when``.
+
+Weights, labels and distances travel as lane-dense ``(1, P)`` rows in
+``(1, tile_p)`` blocks, for the same reason as in ``kmeans_assign``: the
+TPU compiler refuses a ``(tile_p,)`` block of a 1-D array.
 """
 
 from __future__ import annotations
@@ -48,8 +52,8 @@ def _kernel(x_ref, c_ref, w_ref, labels_ref, d2_ref, sums_ref, counts_ref):
 
     x = x_ref[...].astype(jnp.float32)               # (TP, D)
     c = c_ref[...].astype(jnp.float32)               # (K, D)
-    w = w_ref[...].astype(jnp.float32)               # (TP,)
     tp = x.shape[0]
+    w = w_ref[...].astype(jnp.float32).reshape(tp, 1)  # (1, TP) -> (TP, 1)
     k = c.shape[0]
 
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)      # (TP, 1)
@@ -61,12 +65,12 @@ def _kernel(x_ref, c_ref, w_ref, labels_ref, d2_ref, sums_ref, counts_ref):
     )                                                # (TP, K) on the MXU
     d2 = x2 - 2.0 * xc + c2[None, :]
     labels = jnp.argmin(d2, axis=-1).astype(jnp.int32)
-    labels_ref[...] = labels
-    d2_ref[...] = jnp.maximum(jnp.min(d2, axis=-1), 0.0)
+    labels_ref[...] = labels.reshape(1, tp)
+    d2_ref[...] = jnp.maximum(jnp.min(d2, axis=-1), 0.0).reshape(1, tp)
 
     # Tile-local weighted one-hot — lives only in VMEM.
     ids = jax.lax.broadcasted_iota(jnp.int32, (tp, k), 1)
-    onehot = jnp.where(ids == labels[:, None], w[:, None], 0.0)   # (TP, K)
+    onehot = jnp.where(ids == labels[:, None], w, 0.0)   # (TP, K)
     sums_ref[...] += jax.lax.dot_general(
         onehot, x,
         dimension_numbers=(((0,), (0,)), ((), ())),
@@ -79,12 +83,12 @@ def _kernel(x_ref, c_ref, w_ref, labels_ref, d2_ref, sums_ref, counts_ref):
 def kmeans_update_pallas(
     x: jax.Array,          # (P, D) — P and D already padded by ops.py
     centroids: jax.Array,  # (K, D) — K padded with +1e6-distance sentinels
-    weights: jax.Array,    # (P,) — padded points carry weight 0
+    weights: jax.Array,    # (1, P) — padded points carry weight 0
     tile_p: int = 512,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Raw kernel invocation; returns ``(labels, d2, sums, counts)`` with
-    ``counts`` shaped ``(1, K)``. Use ``repro.kernels.ops.kmeans_update``
+    ``labels``/``d2`` shaped ``(1, P)`` and ``counts`` ``(1, K)``. Use ``repro.kernels.ops.kmeans_update``
     for the shape-safe public wrapper (padding, sentinels, CPU fallback)."""
     p, d = x.shape
     k, _ = centroids.shape
@@ -95,17 +99,17 @@ def kmeans_update_pallas(
         in_specs=[
             pl.BlockSpec((tile_p, d), lambda i: (i, 0)),
             pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((tile_p,), lambda i: (i,)),
+            pl.BlockSpec((1, tile_p), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((tile_p,), lambda i: (i,)),
-            pl.BlockSpec((tile_p,), lambda i: (i,)),
+            pl.BlockSpec((1, tile_p), lambda i: (0, i)),
+            pl.BlockSpec((1, tile_p), lambda i: (0, i)),
             pl.BlockSpec((k, d), lambda i: (0, 0)),
             pl.BlockSpec((1, k), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((p,), jnp.int32),
-            jax.ShapeDtypeStruct((p,), jnp.float32),
+            jax.ShapeDtypeStruct((1, p), jnp.int32),
+            jax.ShapeDtypeStruct((1, p), jnp.float32),
             jax.ShapeDtypeStruct((k, d), jnp.float32),
             jax.ShapeDtypeStruct((1, k), jnp.float32),
         ],

@@ -10,7 +10,8 @@ Each op:
     pure-jnp reference for very small inputs where padding overhead
     dominates.
 
-Set ``REPRO_FORCE_INTERPRET=1`` to force interpret mode on any backend.
+Set ``REPRO_FORCE_INTERPRET=1`` to force interpret mode on any backend —
+a switch for the kernel tests, never for a run that measures the chip.
 
 Every wrapper records which tier it dispatched to via
 ``obs.kernel_dispatch`` (a labeled counter + optional trace event). The
@@ -107,7 +108,7 @@ def kmeans_assign(x: jax.Array, centroids: jax.Array,
     xp = _pad_to(_pad_to(x, 1, 128), 0, tile_p)
     cp = _pad_to(_pad_to(centroids, 1, 128), 0, 8, value=1e6)
     labels, d2 = kmeans_assign_pallas(xp, cp, tile_p=tile_p, interpret=_interpret())
-    return labels[:p], d2[:p]
+    return labels[0, :p], d2[0, :p]
 
 
 def cosine_assign(x: jax.Array, signatures: jax.Array,
@@ -129,7 +130,7 @@ def cosine_assign(x: jax.Array, signatures: jax.Array,
     sp = _pad_to(_pad_to(signatures, 1, 128), 0, 8)
     labels, score = cosine_assign_pallas(
         xp, sp, k_valid=k, tile_p=tile_p, interpret=_interpret())
-    return labels[:p], score[:p]
+    return labels[0, :p], score[0, :p]
 
 
 def cosine_topk(x: jax.Array, signatures: jax.Array, k: int,
@@ -178,10 +179,10 @@ def kmeans_update(x: jax.Array, centroids: jax.Array,
     w = jnp.ones((p,), jnp.float32) if weights is None else weights.astype(jnp.float32)
     xp = _pad_to(_pad_to(x, 1, 128), 0, tile_p)
     cp = _pad_to(_pad_to(centroids, 1, 128), 0, 8, value=1e6)
-    wp = _pad_to(w, 0, tile_p)
+    wp = _pad_to(w, 0, tile_p).reshape(1, -1)
     labels, d2, sums, counts = kmeans_update_pallas(
         xp, cp, wp, tile_p=tile_p, interpret=_interpret())
-    return labels[:p], d2[:p], sums[:k, :d], counts[0, :k]
+    return labels[0, :p], d2[0, :p], sums[:k, :d], counts[0, :k]
 
 
 def spmm(a, b: jax.Array, *, transpose: bool = False) -> jax.Array:
@@ -347,8 +348,8 @@ def bipartite_normalize(a: jax.Array, eps: float = 1e-8,
     d1 = jnp.sum(aa, axis=1)
     d2 = jnp.sum(aa, axis=0)
     ap = _pad_to(_pad_to(a, 0, tile_m), 1, tile_n)
-    d1p = _pad_to(d1, 0, tile_m, value=1.0)
-    d2p = _pad_to(d2, 0, tile_n, value=1.0)
+    d1p = _pad_to(d1, 0, tile_m, value=1.0).reshape(1, -1)
+    d2p = _pad_to(d2, 0, tile_n, value=1.0).reshape(1, -1)
     out = scale_apply_pallas(ap, d1p, d2p, tile_m=tile_m, tile_n=tile_n,
                              eps=eps, interpret=_interpret())
     d1_isqrt = jax.lax.rsqrt(jnp.maximum(d1, eps))

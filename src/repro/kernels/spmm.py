@@ -385,8 +385,20 @@ def _tile(blk_ref, rs_ref, cs_ref, g):
     """
     tile = blk_ref[0]
     if rs_ref is not None:
-        tile = tile * rs_ref[0][:, None] * cs_ref[0][None, :]
+        tile = tile * rs_ref[0, 0][:, None] * cs_ref[0, 0][None, :]
     return tile
+
+
+def _scale_views(row_scale, col_scale):
+    """``(n_tr, 1, bm)`` / ``(n_tc, 1, bk)`` views of the pending scales.
+
+    Each grid step reads one tile-row's ``bm`` scales. As a ``(1, bm)``
+    block of the 2-D ``(n_tr, bm)`` array that breaks the TPU's (8, 128)
+    block rule, and the chip's compiler refuses it; a ``(1, 1, bm)``
+    block of the 3-D view is legal, since its last two dims equal the
+    array's.
+    """
+    return row_scale[:, None, :], col_scale[:, None, :]
 
 
 def _kernel(*refs, scaled: bool):
@@ -435,10 +447,10 @@ def spmm_pallas(
     operands = [blocks]
     if scaled:
         in_specs += [
-            pl.BlockSpec((1, bm), lambda j, g, rows, cols: (rows[g], 0)),
-            pl.BlockSpec((1, bk), lambda j, g, rows, cols: (cols[g], 0)),
+            pl.BlockSpec((1, 1, bm), lambda j, g, rows, cols: (rows[g], 0, 0)),
+            pl.BlockSpec((1, 1, bk), lambda j, g, rows, cols: (cols[g], 0, 0)),
         ]
-        operands += [row_scale, col_scale]
+        operands += _scale_views(row_scale, col_scale)
     in_specs.append(
         pl.BlockSpec((bk, bn), lambda j, g, rows, cols: (cols[g], j)))
     operands.append(b)
@@ -508,12 +520,12 @@ def spmm_t_pallas(
     operands = [blocks]
     if scaled:
         in_specs += [
-            pl.BlockSpec((1, bm),
-                         lambda j, g, rows, cols, order: (rows[order[g]], 0)),
-            pl.BlockSpec((1, bk),
-                         lambda j, g, rows, cols, order: (cols[order[g]], 0)),
+            pl.BlockSpec((1, 1, bm),
+                         lambda j, g, rows, cols, order: (rows[order[g]], 0, 0)),
+            pl.BlockSpec((1, 1, bk),
+                         lambda j, g, rows, cols, order: (cols[order[g]], 0, 0)),
         ]
-        operands += [row_scale, col_scale]
+        operands += _scale_views(row_scale, col_scale)
     in_specs.append(
         pl.BlockSpec((bm, bn),
                      lambda j, g, rows, cols, order: (rows[order[g]], j)))
@@ -619,10 +631,12 @@ def spmm_ata_pallas(
     operands = [blocks]
     if scaled:
         in_specs += [
-            pl.BlockSpec((1, bm), lambda j, p, g, rows, cols: (rows[g], 0)),
-            pl.BlockSpec((1, bk), lambda j, p, g, rows, cols: (cols[g], 0)),
+            pl.BlockSpec((1, 1, bm),
+                         lambda j, p, g, rows, cols: (rows[g], 0, 0)),
+            pl.BlockSpec((1, 1, bk),
+                         lambda j, p, g, rows, cols: (cols[g], 0, 0)),
         ]
-        operands += [row_scale, col_scale]
+        operands += _scale_views(row_scale, col_scale)
     in_specs.append(
         pl.BlockSpec((bk, bn), lambda j, p, g, rows, cols: (cols[g], j)))
     operands.append(x)

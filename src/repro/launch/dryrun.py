@@ -143,13 +143,14 @@ def dryrun_lamc_cell(shape_name: str, mesh_name: str) -> dict:
     coll = rl.collective_bytes_from_hlo(hlo)
     flops = float(cost.get("flops", 0.0))
     hbytes = float(cost.get("bytes accessed", 0.0))
+    hw = rl.peaks(rl.V5E)
     rec = dict(
         arch="lamc-coclustering", shape=shape_name, mesh=mesh_name,
         chips=chips, hlo_flops=flops, hlo_bytes=hbytes,
         collective_bytes=coll["total"], collectives=coll,
-        compute_s=flops / (chips * rl.HW["flops_bf16"]),
-        memory_s=hbytes / (chips * rl.HW["hbm_bw"]),
-        collective_s=coll["total"] / (chips * rl.HW["ici_bw"]),
+        compute_s=flops / (chips * hw["flops_bf16"]),
+        memory_s=hbytes / (chips * hw["hbm_bw"]),
+        collective_s=coll["total"] / (chips * hw["ici_bw"]),
         lower_s=round(lower_s, 1), compile_s=round(compile_s, 1),
         status="ok",
     )

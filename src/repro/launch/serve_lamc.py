@@ -206,8 +206,8 @@ def serve_service(ckpt_dir: str, *, batch: int = 64, requests: int = 32,
     batches, worker replicas — and reports the *service's* latency
     percentiles (submit → fulfil, which includes queueing). Requests are
     quarter-batch sized so the coalescer has real work to do; every
-    ticket is awaited and checked, so a reject or a dropped request
-    fails loudly rather than skewing the stats.
+    ticket, warm-up included, is awaited and checked, so a reject or a
+    dropped request fails loudly rather than skewing the stats.
     """
     model, meta = streaming.load_model(ckpt_dir)
     reg = obs.Registry()
@@ -219,19 +219,20 @@ def serve_service(ckpt_dir: str, *, batch: int = 64, requests: int = 32,
     t_wall = time.perf_counter()
     with streaming.AssignService(model, version="serve_lamc",
                                  config=cfg, metrics=reg) as svc:
-        for _ in range(warmup):
-            svc.submit(base, axis=axis, k=k).result(timeout=60.0)
-        t_wall = time.perf_counter()
-        tickets = [svc.submit(base + np.float32(i), axis=axis, k=k)
-                   for i in range(requests)]
-        rows_served = 0
-        for t in tickets:
-            res = t.result(timeout=60.0)
+        def served_rows(ticket) -> int:
+            res = ticket.result(timeout=60.0)
             if not res.ok:
                 raise RuntimeError(
                     f"service rejected a well-formed request: "
                     f"{res.reason}: {res.detail}")
-            rows_served += len(res.labels)
+            return len(res.labels)
+
+        for _ in range(warmup):
+            served_rows(svc.submit(base, axis=axis, k=k))
+        t_wall = time.perf_counter()
+        tickets = [svc.submit(base + np.float32(i), axis=axis, k=k)
+                   for i in range(requests)]
+        rows_served = sum(served_rows(t) for t in tickets)
         wall_s = time.perf_counter() - t_wall
         stats = svc.stats()
     qps = rows_served / max(wall_s, 1e-9)
@@ -275,6 +276,9 @@ def main(argv=None):
                          "(implies enabling obs spans)")
     args = ap.parse_args(argv)
 
+    from repro.runtime import compile_cache
+
+    compile_cache.enable()
     if args.trace_out:
         obs.configure(enabled=True)
     if obs.enabled():

@@ -104,11 +104,12 @@ def main():
     args = ap.parse_args()
     c0, c1, total = unit_census(args.arch, args.shape, args.multipod)
     chips = 512 if args.multipod else 256
+    hw = rl.peaks(rl.V5E)
     print(json.dumps({
         "c0_coll": c0["coll"], "c1_coll": c1["coll"],
         "extrapolated": total,
-        "coll_s_per_dev": total["coll_total"] / chips / rl.HW["ici_bw"],
-        "flops_s": total["flops"] * chips / chips / rl.HW["flops_bf16"],
+        "coll_s_per_dev": total["coll_total"] / chips / hw["ici_bw"],
+        "flops_s": total["flops"] * chips / chips / hw["flops_bf16"],
     }, indent=1))
 
 

@@ -1,3 +1,3 @@
-from . import fault_tolerance, shardings
+from . import compile_cache, fault_tolerance, shardings
 
-__all__ = ["fault_tolerance", "shardings"]
+__all__ = ["compile_cache", "fault_tolerance", "shardings"]

@@ -369,7 +369,9 @@ class StreamingCocluster:
         if _sparse.is_bcoo(chunk):
             vals = np.asarray(_sparse.gather_rows_dense(chunk, jnp.asarray(rows)))
         else:
-            vals = np.asarray(chunk)[rows].astype(np.float32)
+            # gather where the chunk lives: a device chunk sends only the
+            # kept rows to the host, not all r of them
+            vals = np.asarray(chunk[rows]).astype(np.float32)
         self._res_vals[slots] = vals
 
     # ------------------------------------------------------------------- fold

@@ -19,6 +19,7 @@ own jit trace.
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -26,6 +27,20 @@ import pytest
 from repro.core import LAMCConfig, lamc_cocluster
 from repro.core.partition import PartitionPlan
 from repro.data import planted_cocluster_matrix, to_bcoo
+
+
+@pytest.fixture(autouse=True)
+def _drop_compiled():
+    """Drop every compiled program after each case.
+
+    Each case compiles programs of its own (plan and config are static),
+    and a process that keeps them adds about 5,400 memory maps per case:
+    the whole sweep in one process (or one test worker that ran other
+    files first) crosses the kernel's 65,530-map limit and the next XLA
+    compile segfaults.
+    """
+    yield
+    jax.clear_caches()
 
 
 def _draw_case(seed: int):

@@ -164,6 +164,37 @@ class TestAdmission:
             res2 = svc.submit(x).result(timeout=60.0)
             assert res2.ok
 
+    @pytest.mark.parametrize("driver", ["serve_lamc", "bench_serve"])
+    def test_drivers_fail_on_internal_error(self, fitted, tmp_path,
+                                            monkeypatch, driver):
+        """A scorer that raises must fail the serving drivers, not let
+        them exit 0 over a service that scored nothing."""
+        from repro.streaming import serve as serve_mod
+
+        def boom(x):
+            raise RuntimeError("injected scorer failure")
+
+        monkeypatch.setattr(serve_mod._Engine, "warm",
+                            lambda self, axis, k, batch: None)
+        monkeypatch.setattr(serve_mod._Engine, "scorer",
+                            lambda self, axis, k: boom)
+        model, _ = fitted
+        if driver == "serve_lamc":
+            from repro.launch import serve_lamc
+
+            ckpt = str(tmp_path / "model")
+            streaming.save_model(ckpt, model)
+            with pytest.raises(RuntimeError, match="internal_error"):
+                serve_lamc.serve_service(ckpt, batch=16, requests=4,
+                                         warmup=1, replicas=1)
+        else:
+            from benchmarks import bench_serve
+
+            x = np.zeros((4, model.n_cols), np.float32)
+            d = bench_serve._drive(model, [x, x, x], axis="rows", k=1,
+                                   replicas=1, batch=16)
+            assert d["errors"] == 4      # the warm-up and all three
+
     def test_shutdown_rejects_after_close(self, fitted):
         model, _ = fitted
         svc = _service(model)
