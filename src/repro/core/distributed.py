@@ -129,8 +129,11 @@ def lamc_step_fn(cfg: LAMCConfig, plan: partition.PartitionPlan,
         static shapes everywhere, zero communication.
         """
         row_labels, col_labels = jax.vmap(_atom_fn(cfg))(keys, blocks)
-        row_sigs, row_counts = merging.atom_signatures(row_feats, row_labels, cfg.atom_k)
-        col_sigs, col_counts = merging.atom_signatures(col_feats, col_labels, cfg.atom_d)
+        with jax.named_scope("signatures"):
+            row_sigs, row_counts = merging.atom_signatures(
+                row_feats, row_labels, cfg.atom_k)
+            col_sigs, col_counts = merging.atom_signatures(
+                col_feats, col_labels, cfg.atom_d)
         return row_labels, col_labels, row_sigs, row_counts, col_sigs, col_counts
 
     atom_phase = shard_map(
@@ -247,38 +250,50 @@ def lamc_step_fn(cfg: LAMCConfig, plan: partition.PartitionPlan,
     )
 
     def step(a):
-        kroot = jax.random.key(plan.seed + 7)
-        kar, kac, kmerge = jax.random.split(kroot, 3)
-        anchor_rows = merging.anchor_indices(kar, plan.n_rows, cfg.signature_dim)
-        anchor_cols = merging.anchor_indices(kac, plan.n_cols, cfg.signature_dim)
+        # device phases as named scopes, as in lamc._lamc_jit (DESIGN.md
+        # §14); the merge's collectives fall under merge
+        with jax.named_scope("signatures"):
+            kroot = jax.random.key(plan.seed + 7)
+            kar, kac, kmerge = jax.random.split(kroot, 3)
+            anchor_rows = merging.anchor_indices(kar, plan.n_rows,
+                                                 cfg.signature_dim)
+            anchor_cols = merging.anchor_indices(kac, plan.n_cols,
+                                                 cfg.signature_dim)
         b = plan.blocks_per_resample
-        i_of_b = jnp.arange(b) // plan.n
-        j_of_b = jnp.arange(b) % plan.n
+        with jax.named_scope("extract"):
+            i_of_b = jnp.arange(b) // plan.n
+            j_of_b = jnp.arange(b) % plan.n
         extract_fn = (partition.extract_blocks_sparse
                       if cfg.input_format == "bcoo" else partition.extract_blocks)
 
         def extract(t):
             # phase 1: block scatter (GSPMD all-to-all, data moves once)
-            blocks, row_idx, col_idx = extract_fn(a, plan, t)
-            keys = jax.vmap(
-                lambda i: jax.random.fold_in(
-                    jax.random.fold_in(jax.random.key(plan.seed + 1), t), i)
-            )(jnp.arange(b))
-            # anchor slivers first ((M, q_row) / (q_col, N)) — indexing rows
-            # first would materialize an (m, phi, N) intermediate (same
-            # gather-order fix as extract_blocks).
-            row_sliver, col_sliver = anchor_features(a, anchor_rows, anchor_cols)
-            row_feats = row_sliver[row_idx][i_of_b]             # (B, phi, q_row)
-            col_feats = jnp.transpose(
-                col_sliver[:, col_idx], (1, 2, 0))[j_of_b]      # (B, psi, q_col)
-            return blocks, keys, row_feats, col_feats, row_idx[i_of_b], col_idx[j_of_b]
+            with jax.named_scope("extract"):
+                blocks, row_idx, col_idx = extract_fn(a, plan, t)
+                row_pos, col_pos = row_idx[i_of_b], col_idx[j_of_b]
+            with jax.named_scope("atom/svd"):
+                keys = jax.vmap(
+                    lambda i: jax.random.fold_in(
+                        jax.random.fold_in(jax.random.key(plan.seed + 1), t), i)
+                )(jnp.arange(b))
+            with jax.named_scope("signatures"):
+                # anchor slivers first ((M, q_row) / (q_col, N)) — indexing
+                # rows first would materialize an (m, phi, N) intermediate
+                # (same gather-order fix as extract_blocks).
+                row_sliver, col_sliver = anchor_features(a, anchor_rows,
+                                                         anchor_cols)
+                row_feats = row_sliver[row_idx][i_of_b]         # (B, phi, q_row)
+                col_feats = jnp.transpose(
+                    col_sliver[:, col_idx], (1, 2, 0))[j_of_b]  # (B, psi, q_col)
+            return blocks, keys, row_feats, col_feats, row_pos, col_pos
 
         if resample_axis is None:
             # resamples run sequentially (lax.scan) — single-pod path
             def body(_, t):
                 blocks, keys, row_feats, col_feats, row_pos, col_pos = extract(t)
-                blocks = jax.lax.with_sharding_constraint(
-                    blocks, NamedSharding(mesh, block_spec))
+                with jax.named_scope("extract"):
+                    blocks = jax.lax.with_sharding_constraint(
+                        blocks, NamedSharding(mesh, block_spec))
                 rl, cl, rs, rc, cs, cc = atom_phase(blocks, keys, row_feats,
                                                     col_feats)
                 return None, dict(
@@ -293,39 +308,42 @@ def lamc_step_fn(cfg: LAMCConfig, plan: partition.PartitionPlan,
             # (pod, (data, model), ...) — one block-task per device, no
             # duplicated work across pods.
             ext = jax.vmap(extract)(jnp.arange(plan.t_p))
-            blocks_t = jax.lax.with_sharding_constraint(
-                ext[0], NamedSharding(mesh, P(resample_axis, axes, None, None)))
+            with jax.named_scope("extract"):
+                blocks_t = jax.lax.with_sharding_constraint(
+                    ext[0], NamedSharding(mesh, P(resample_axis, axes, None,
+                                                  None)))
             rl, cl, rs, rc, cs, cc = atom_phase_tp(
                 blocks_t, ext[1], ext[2], ext[3])
             stk = dict(row_labels=rl, col_labels=cl, row_sigs=rs,
                        row_counts=rc, col_sigs=cs, col_counts=cc,
                        row_pos=ext[4], col_pos=ext[5])
 
-        # phase 3: one hierarchical merge across all resamples
-        row_votes, col_votes = merge(
-            stk["row_sigs"], stk["row_counts"], stk["row_labels"], stk["row_pos"],
-            stk["col_sigs"], stk["col_counts"], stk["col_labels"], stk["col_pos"],
-            kmerge,
-        )
-        # assignment semantics shared with the single-host merge: the psum'd
-        # vote tables are bit-identical to the single-host scatter (small
-        # integer counts in f32, exact under any summation order), so the
-        # labels AND the overlap memberships match bit-for-bit at equal
-        # seeds (DESIGN.md §11).
-        row_labels, row_member = merging.finalize_assignment(
-            row_votes, cfg.assignment, cfg.overlap_threshold,
-            cfg.min_membership)
-        col_labels, col_member = merging.finalize_assignment(
-            col_votes, cfg.assignment, cfg.overlap_threshold,
-            cfg.min_membership)
-        # serving signatures: cluster means over the anchor slivers under the
-        # final consensus labels — tiny (K x q), replicated; GSPMD emits the
-        # gathers for the sliver reads of the sharded matrix.
-        row_sliver, col_sliver = anchor_features(a, anchor_rows, anchor_cols)
-        row_sigs, row_mean, _ = merging.cluster_signatures(
-            row_sliver, row_labels, cfg.n_row_clusters)
-        col_sigs, col_mean, _ = merging.cluster_signatures(
-            col_sliver.T, col_labels, cfg.n_col_clusters)
+        with jax.named_scope("merge"):
+            # phase 3: one hierarchical merge across all resamples
+            row_votes, col_votes = merge(
+                stk["row_sigs"], stk["row_counts"], stk["row_labels"], stk["row_pos"],
+                stk["col_sigs"], stk["col_counts"], stk["col_labels"], stk["col_pos"],
+                kmerge,
+            )
+            # assignment semantics shared with the single-host merge: the psum'd
+            # vote tables are bit-identical to the single-host scatter (small
+            # integer counts in f32, exact under any summation order), so the
+            # labels AND the overlap memberships match bit-for-bit at equal
+            # seeds (DESIGN.md §11).
+            row_labels, row_member = merging.finalize_assignment(
+                row_votes, cfg.assignment, cfg.overlap_threshold,
+                cfg.min_membership)
+            col_labels, col_member = merging.finalize_assignment(
+                col_votes, cfg.assignment, cfg.overlap_threshold,
+                cfg.min_membership)
+            # serving signatures: cluster means over the anchor slivers under the
+            # final consensus labels — tiny (K x q), replicated; GSPMD emits the
+            # gathers for the sliver reads of the sharded matrix.
+            row_sliver, col_sliver = anchor_features(a, anchor_rows, anchor_cols)
+            row_sigs, row_mean, _ = merging.cluster_signatures(
+                row_sliver, row_labels, cfg.n_row_clusters)
+            col_sigs, col_mean, _ = merging.cluster_signatures(
+                col_sliver.T, col_labels, cfg.n_col_clusters)
         return dict(
             row_labels=row_labels,
             col_labels=col_labels,
@@ -373,14 +391,15 @@ def distributed_lamc(mesh: Mesh, a: jax.Array, cfg: LAMCConfig,
             step, in_sh, out_sh = lamc_step_fn(cfg, plan, mesh, block_axes,
                                                resample_axis=resample_axis)
             step_c = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh)
-        # All three distributed phases (scatter -> atoms -> merge) are one
-        # XLA program; one fenced span covers the lot (DESIGN.md §14).
-        with obs.span("pipeline",
-                      phases="scatter->atom->merge") as ps:
+        # One XLA program runs the phases, named scopes on the device
+        # (DESIGN.md §14); on the host, the call and the wait for it.
+        with obs.span("dispatch"):
             with in_sh.mesh:          # lamc_step_fn's Auto view of mesh
-                out = ps.fence(step_c(a))
-        with obs.span("finalize") as fs:
-            return fs.fence(LAMCResult(
+                out = step_c(a)
+        with obs.span("wait") as ws:
+            ws.fence(out)
+        with obs.span("finalize"):
+            return LAMCResult(
                 out["row_labels"], out["col_labels"],
                 out["row_votes"], out["col_votes"], plan,
                 row_sigs=out["row_sigs"], col_sigs=out["col_sigs"],
@@ -388,4 +407,4 @@ def distributed_lamc(mesh: Mesh, a: jax.Array, cfg: LAMCConfig,
                 anchor_rows=out["anchor_rows"],
                 anchor_cols=out["anchor_cols"],
                 row_membership=out["row_membership"],
-                col_membership=out["col_membership"]))
+                col_membership=out["col_membership"])

@@ -163,8 +163,9 @@ def run_resample(a, plan, cfg: LAMCConfig, anchor_rows, anchor_cols, t,
     b = plan.blocks_per_resample
     if operator is not None:
         assert b == 1, "operator path requires a single-block plan"
-        key_b = jax.random.fold_in(
-            jax.random.fold_in(jax.random.key(plan.seed + 1), t), 0)
+        with jax.named_scope("atom/svd"):
+            key_b = jax.random.fold_in(
+                jax.random.fold_in(jax.random.key(plan.seed + 1), t), 0)
         res = spectral.scc(
             key_b, operator, cfg.atom_k, cfg.atom_d,
             svd_iters=cfg.svd_iters, kmeans_iters=cfg.kmeans_iters,
@@ -173,30 +174,34 @@ def run_resample(a, plan, cfg: LAMCConfig, anchor_rows, anchor_cols, t,
         )
         row_labels = res.row_labels[None]                  # (1, phi)
         col_labels = res.col_labels[None]                  # (1, psi)
-        row_idx = jnp.arange(plan.n_rows, dtype=jnp.int32).reshape(
-            plan.m, plan.phi)
-        col_idx = jnp.arange(plan.n_cols, dtype=jnp.int32).reshape(
-            plan.n, plan.psi)
+        with jax.named_scope("extract"):
+            row_idx = jnp.arange(plan.n_rows, dtype=jnp.int32).reshape(
+                plan.m, plan.phi)
+            col_idx = jnp.arange(plan.n_cols, dtype=jnp.int32).reshape(
+                plan.n, plan.psi)
     else:
         extract = (partition.extract_blocks_sparse
                    if cfg.input_format == "bcoo" else partition.extract_blocks)
-        blocks, row_idx, col_idx = extract(a, plan, t)
-        keys = jax.vmap(
-            lambda i: jax.random.fold_in(jax.random.fold_in(jax.random.key(plan.seed + 1), t), i)
-        )(jnp.arange(b))
+        with jax.named_scope("extract"):
+            blocks, row_idx, col_idx = extract(a, plan, t)
+        with jax.named_scope("atom/svd"):
+            keys = jax.vmap(
+                lambda i: jax.random.fold_in(jax.random.fold_in(jax.random.key(plan.seed + 1), t), i)
+            )(jnp.arange(b))
         row_labels, col_labels = jax.vmap(_atom_fn(cfg))(keys, blocks)  # (B,phi),(B,psi)
 
-    # anchor features: every block's points restricted to the shared anchors
-    j_of_b = jnp.arange(b) % plan.n
-    i_of_b = jnp.arange(b) // plan.n
-    row_sliver, col_sliver = anchor_features(a, anchor_rows, anchor_cols)
-    row_feats = row_sliver[row_idx]                    # (m, phi, q)
-    col_feats = col_sliver[:, col_idx]                 # (q, n, psi)
-    col_feats = jnp.transpose(col_feats, (1, 2, 0))    # (n, psi, q)
-    row_sigs, row_counts = merging.atom_signatures(
-        row_feats[i_of_b], row_labels, cfg.atom_k)
-    col_sigs, col_counts = merging.atom_signatures(
-        col_feats[j_of_b], col_labels, cfg.atom_d)
+    with jax.named_scope("signatures"):
+        # anchor features: every block's points restricted to the shared anchors
+        j_of_b = jnp.arange(b) % plan.n
+        i_of_b = jnp.arange(b) // plan.n
+        row_sliver, col_sliver = anchor_features(a, anchor_rows, anchor_cols)
+        row_feats = row_sliver[row_idx]                    # (m, phi, q)
+        col_feats = col_sliver[:, col_idx]                 # (q, n, psi)
+        col_feats = jnp.transpose(col_feats, (1, 2, 0))    # (n, psi, q)
+        row_sigs, row_counts = merging.atom_signatures(
+            row_feats[i_of_b], row_labels, cfg.atom_k)
+        col_sigs, col_counts = merging.atom_signatures(
+            col_feats[j_of_b], col_labels, cfg.atom_d)
     return dict(
         row_sigs=row_sigs, row_counts=row_counts, row_labels=row_labels,
         row_index=row_idx,
@@ -209,10 +214,14 @@ def run_resample(a, plan, cfg: LAMCConfig, anchor_rows, anchor_cols, t,
 def _lamc_jit(a, cfg: LAMCConfig, plan: partition.PartitionPlan,
               operator=None, block_mask=None):
     q = cfg.signature_dim
-    kproj = jax.random.key(plan.seed + 7)
-    kar, kac, kmerge = jax.random.split(kproj, 3)
-    anchor_rows = merging.anchor_indices(kar, plan.n_rows, q)
-    anchor_cols = merging.anchor_indices(kac, plan.n_cols, q)
+    # Device phases as named scopes (DESIGN.md §14): every instruction of
+    # the program carries extract, atom/{normalize,svd,kmeans}, signatures
+    # or merge in its op_name; only the T_p scan's loop control has none.
+    with jax.named_scope("signatures"):
+        kproj = jax.random.key(plan.seed + 7)
+        kar, kac, kmerge = jax.random.split(kproj, 3)
+        anchor_rows = merging.anchor_indices(kar, plan.n_rows, q)
+        anchor_cols = merging.anchor_indices(kac, plan.n_cols, q)
 
     def body(_, t):
         out = run_resample(a, plan, cfg, anchor_rows, anchor_cols, t,
@@ -220,23 +229,24 @@ def _lamc_jit(a, cfg: LAMCConfig, plan: partition.PartitionPlan,
         return None, out
 
     _, stacked = jax.lax.scan(body, None, jnp.arange(plan.t_p))
-    # serving signatures are cluster means over the same anchor slivers the
-    # merge consumes — computed from the final consensus labels
-    row_sliver, col_sliver = anchor_features(a, anchor_rows, anchor_cols)
-    merged = merging.signature_merge(
-        kmerge,
-        n_rows=plan.n_rows, n_cols=plan.n_cols,
-        k_row=cfg.n_row_clusters, k_col=cfg.n_col_clusters,
-        m=plan.m, n=plan.n,
-        kmeans_iters=cfg.merge_kmeans_iters,
-        n_restarts=cfg.merge_restarts,
-        row_features=row_sliver, col_features=col_sliver.T,
-        assignment=cfg.assignment,
-        overlap_threshold=cfg.overlap_threshold,
-        min_membership=cfg.min_membership,
-        block_mask=block_mask,
-        **stacked,
-    )
+    with jax.named_scope("merge"):
+        # serving signatures are cluster means over the same anchor slivers
+        # the merge consumes — computed from the final consensus labels
+        row_sliver, col_sliver = anchor_features(a, anchor_rows, anchor_cols)
+        merged = merging.signature_merge(
+            kmerge,
+            n_rows=plan.n_rows, n_cols=plan.n_cols,
+            k_row=cfg.n_row_clusters, k_col=cfg.n_col_clusters,
+            m=plan.m, n=plan.n,
+            kmeans_iters=cfg.merge_kmeans_iters,
+            n_restarts=cfg.merge_restarts,
+            row_features=row_sliver, col_features=col_sliver.T,
+            assignment=cfg.assignment,
+            overlap_threshold=cfg.overlap_threshold,
+            min_membership=cfg.min_membership,
+            block_mask=block_mask,
+            **stacked,
+        )
     return merged, anchor_rows, anchor_cols
 
 
@@ -329,8 +339,8 @@ def lamc_cocluster(a, cfg: LAMCConfig,
                 # from the pattern cache (core.opcache) when the fit loop
                 # re-prepares a matrix whose sparsity pattern it has seen —
                 # a repeat fit/resample pays a values refresh at most.
-                with obs.span("prepare_operator", route=route):
-                    operator = _sparse.prepare_operator(a, route)
+                with obs.span("prepare_operator", route=route) as ops:
+                    operator = ops.fence(_sparse.prepare_operator(a, route))
         # Resolved-plan attributes on the root span: what actually ran.
         root.set(m=plan.m, n=plan.n, phi=plan.phi, psi=plan.psi,
                  t_p=plan.t_p, spmm_route=plan.spmm_route,
@@ -342,19 +352,20 @@ def lamc_cocluster(a, cfg: LAMCConfig,
                 raise ValueError(
                     f"block_mask must be (t_p, blocks_per_resample) = {want}, "
                     f"got {tuple(block_mask.shape)}")
-        # The partition/extract -> atom -> merge phases fuse into one XLA
-        # program (_lamc_jit), so they share one fenced span: splitting it
-        # would mean splitting the jit (DESIGN.md §14).
-        with obs.span("pipeline",
-                      phases="partition/extract->atom->merge") as ps:
-            merged, anchor_rows, anchor_cols = ps.fence(
-                _lamc_jit(a, cfg, plan, operator, block_mask))
-        with obs.span("finalize") as fs:
-            return fs.fence(LAMCResult(
+        # One XLA program runs extract -> atom -> signatures -> merge; its
+        # phases are named scopes on the device (DESIGN.md §14). On the
+        # host: the call up to its return, then the wait for its outputs.
+        with obs.span("dispatch"):
+            out = _lamc_jit(a, cfg, plan, operator, block_mask)
+        with obs.span("wait") as ws:
+            ws.fence(out)
+        with obs.span("finalize"):
+            merged, anchor_rows, anchor_cols = out
+            return LAMCResult(
                 merged.row_labels, merged.col_labels,
                 merged.row_votes, merged.col_votes, plan,
                 row_sigs=merged.row_sigs, col_sigs=merged.col_sigs,
                 row_mean=merged.row_mean, col_mean=merged.col_mean,
                 anchor_rows=anchor_rows, anchor_cols=anchor_cols,
                 row_membership=merged.row_membership,
-                col_membership=merged.col_membership))
+                col_membership=merged.col_membership)

@@ -45,17 +45,20 @@ def nmtf(key: jax.Array, a: jax.Array, k: int, d: int | None = None,
     """
     if d is None:
         d = k
-    a = a - jnp.minimum(jnp.min(a), 0.0)  # enforce non-negativity
+    # the atom's phases as named scopes (DESIGN.md §14); the multiplicative
+    # updates take the place SCC's subspace iteration has
+    with jax.named_scope("atom/normalize"):
+        a = a - jnp.minimum(jnp.min(a), 0.0)  # enforce non-negativity
     m, n = a.shape
-    kf, kg = jax.random.split(key)
-    # k-means init (Ding et al. recommend it): F = onehot(rows) + 0.2,
-    # G = onehot(cols) + 0.2 — orders of magnitude faster convergence than
-    # random init for the multiplicative updates.
-    row_km = _kmeans.kmeans(kf, a, k, n_iter=8)
-    col_km = _kmeans.kmeans(kg, a.T, d, n_iter=8)
-    f = jax.nn.one_hot(row_km.labels, k, dtype=a.dtype) + 0.2
-    g = jax.nn.one_hot(col_km.labels, d, dtype=a.dtype) + 0.2
-    s = f.T @ a @ g / jnp.maximum(jnp.sum(f, 0)[:, None] * jnp.sum(g, 0)[None, :], _EPS)
+    with jax.named_scope("atom/kmeans"):
+        kf, kg = jax.random.split(key)
+        # k-means init (Ding et al. recommend it): F = onehot(rows) + 0.2,
+        # G = onehot(cols) + 0.2 — orders of magnitude faster convergence
+        # than random init for the multiplicative updates.
+        row_km = _kmeans.kmeans(kf, a, k, n_iter=8)
+        col_km = _kmeans.kmeans(kg, a.T, d, n_iter=8)
+        f = jax.nn.one_hot(row_km.labels, k, dtype=a.dtype) + 0.2
+        g = jax.nn.one_hot(col_km.labels, d, dtype=a.dtype) + 0.2
 
     def step(carry, _):
         f, s, g = carry
@@ -73,11 +76,12 @@ def nmtf(key: jax.Array, a: jax.Array, k: int, d: int | None = None,
         s = s * jnp.sqrt(num_s / jnp.maximum(den_s, _EPS))
         return (f, s, g), None
 
-    (f, s, g), _ = jax.lax.scan(step, (f, s, g), None, length=n_iter)
-    recon = f @ s @ g.T
-    loss = jnp.sum((a - recon) ** 2)
-    return NMTFResult(
-        row_labels=jnp.argmax(f, axis=1).astype(jnp.int32),
-        col_labels=jnp.argmax(g, axis=1).astype(jnp.int32),
-        f=f, s=s, g=g, loss=loss,
-    )
+    with jax.named_scope("atom/factorize"):
+        s = f.T @ a @ g / jnp.maximum(jnp.sum(f, 0)[:, None] * jnp.sum(g, 0)[None, :], _EPS)
+        (f, s, g), _ = jax.lax.scan(step, (f, s, g), None, length=n_iter)
+        recon = f @ s @ g.T
+        loss = jnp.sum((a - recon) ** 2)
+        row_labels = jnp.argmax(f, axis=1).astype(jnp.int32)
+        col_labels = jnp.argmax(g, axis=1).astype(jnp.int32)
+    return NMTFResult(row_labels=row_labels, col_labels=col_labels,
+                      f=f, s=s, g=g, loss=loss)

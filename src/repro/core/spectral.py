@@ -244,29 +244,34 @@ def scc(
         raise ValueError(
             "svd_method='exact' (LAPACK) requires a dense matrix; the sparse "
             "path supports svd_method='randomized' (SpMM subspace iteration)")
-    a_n, d1_isqrt, d2_isqrt = normalize_bipartite(a)
-    ksvd, kkm1, kkm2 = jax.random.split(key, 3)
-    if svd_method == "exact":
-        u, s, vt = exact_svd(a_n, rank=l + 1)
-    else:
-        u, s, vt = randomized_svd(ksvd, a_n, rank=l + 1, n_iter=svd_iters,
-                                  qr_method=qr_method)
-    # Drop the leading (trivial) singular pair: u_2..u_{l+1}, v_2..v_{l+1}.
-    u_hat = u[:, 1 : l + 1]
-    v_hat = vt[1 : l + 1, :].T
-    row_embed = d1_isqrt[:, None] * u_hat           # (M, l)
-    col_embed = d2_isqrt[:, None] * v_hat           # (N, l)
+    # the atom's phases as named scopes (DESIGN.md §14): the device trace
+    # names each instruction's phase, in every program that runs an atom
+    with jax.named_scope("atom/normalize"):
+        a_n, d1_isqrt, d2_isqrt = normalize_bipartite(a)
+    with jax.named_scope("atom/svd"):
+        ksvd, kkm1, kkm2 = jax.random.split(key, 3)
+        if svd_method == "exact":
+            u, s, vt = exact_svd(a_n, rank=l + 1)
+        else:
+            u, s, vt = randomized_svd(ksvd, a_n, rank=l + 1, n_iter=svd_iters,
+                                      qr_method=qr_method)
+        # Drop the leading (trivial) singular pair: u_2..u_{l+1}, v_2..v_{l+1}.
+        u_hat = u[:, 1 : l + 1]
+        v_hat = vt[1 : l + 1, :].T
+        row_embed = d1_isqrt[:, None] * u_hat           # (M, l)
+        col_embed = d2_isqrt[:, None] * v_hat           # (N, l)
 
-    if k == d:
-        z = jnp.concatenate([row_embed, col_embed], axis=0)
-        res = _kmeans.kmeans(kkm1, z, k, n_iter=kmeans_iters, assign_impl=assign_impl)
-        row_labels = res.labels[: a.shape[0]]
-        col_labels = res.labels[a.shape[0] :]
-        inertia = res.inertia
-    else:
-        res_r = _kmeans.kmeans(kkm1, row_embed, k, n_iter=kmeans_iters, assign_impl=assign_impl)
-        res_c = _kmeans.kmeans(kkm2, col_embed, d, n_iter=kmeans_iters, assign_impl=assign_impl)
-        row_labels, col_labels = res_r.labels, res_c.labels
-        inertia = res_r.inertia + res_c.inertia
+    with jax.named_scope("atom/kmeans"):
+        if k == d:
+            z = jnp.concatenate([row_embed, col_embed], axis=0)
+            res = _kmeans.kmeans(kkm1, z, k, n_iter=kmeans_iters, assign_impl=assign_impl)
+            row_labels = res.labels[: a.shape[0]]
+            col_labels = res.labels[a.shape[0] :]
+            inertia = res.inertia
+        else:
+            res_r = _kmeans.kmeans(kkm1, row_embed, k, n_iter=kmeans_iters, assign_impl=assign_impl)
+            res_c = _kmeans.kmeans(kkm2, col_embed, d, n_iter=kmeans_iters, assign_impl=assign_impl)
+            row_labels, col_labels = res_r.labels, res_c.labels
+            inertia = res_r.inertia + res_c.inertia
 
     return SCCResult(row_labels, col_labels, row_embed, col_embed, inertia)

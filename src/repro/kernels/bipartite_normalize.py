@@ -56,4 +56,5 @@ def scale_apply_pallas(
         out_specs=pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         interpret=interpret,
+        name="bipartite_normalize",
     )(a, d1, d2)

@@ -90,6 +90,7 @@ def kmeans_assign_pallas(
             jax.ShapeDtypeStruct((1, p), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_assign",
     )(x, centroids)
 
 
@@ -169,6 +170,7 @@ def cosine_topk_pallas(
             jax.ShapeDtypeStruct((p, k_top), jnp.float32),
         ],
         interpret=interpret,
+        name="cosine_topk",
     )(x, signatures)
 
 
@@ -202,4 +204,5 @@ def cosine_assign_pallas(
             jax.ShapeDtypeStruct((1, p), jnp.float32),
         ],
         interpret=interpret,
+        name="cosine_assign",
     )(x, signatures)

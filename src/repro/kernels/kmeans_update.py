@@ -114,4 +114,5 @@ def kmeans_update_pallas(
             jax.ShapeDtypeStruct((1, k), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_update",
     )(x, centroids, weights)

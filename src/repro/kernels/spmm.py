@@ -465,6 +465,7 @@ def spmm_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_out, n), jnp.float32),
         interpret=interpret,
+        name="spmm",
     )(block_rows, block_cols, *operands)
 
 
@@ -542,6 +543,7 @@ def spmm_t_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((k_out, n), jnp.float32),
         interpret=interpret,
+        name="spmm_t",
     )(block_rows, block_cols, t_order, *operands)
 
 
@@ -660,4 +662,5 @@ def spmm_ata_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="spmm_ata",
     )(block_rows, block_cols, *operands)

@@ -21,6 +21,13 @@ Two rules make the numbers honest and the hot paths safe:
   shared no-op singleton: no allocation, no clock reads, no fencing —
   instrumented code pays one dict lookup and one no-op ``with``.
 
+When enabled, every span also enters a
+``jax.profiler.TraceAnnotation("obs." + name)`` and leaves it after its
+fence, so a profiler trace holds the same spans on its own clock; and
+every JAX trace, lowering, backend compile and persistent-cache load
+reported by ``jax.monitoring`` while a span is open is recorded as a
+``compile`` child span of it (:func:`_on_compile`).
+
 Hooks live strictly outside jit: spans never touch tracer values (fence
 stores a reference, it never inspects), attrs must be host scalars, and
 nothing here forces a device sync except the explicit exit fence.
@@ -38,14 +45,25 @@ __all__ = ["Span", "Trace", "span", "event", "configure", "enabled",
 #: bumped when the JSONL row shape changes; validators check it.
 TRACE_SCHEMA_VERSION = 1
 
-_cfg = {"enabled": os.environ.get("REPRO_OBS", "") not in ("", "0")}
+_cfg = {"enabled": os.environ.get("REPRO_OBS", "") not in ("", "0"),
+        "listening": False}
 _tls = threading.local()
+
+#: ``jax.monitoring`` duration events recorded as ``compile`` spans.
+COMPILE_EVENTS = frozenset({
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+})
 
 
 def configure(enabled: bool | None = None) -> None:
     """Flip the global span switch (``None`` leaves it unchanged)."""
     if enabled is not None:
         _cfg["enabled"] = bool(enabled)
+    if _cfg["enabled"] and not _cfg["listening"]:
+        _listen()
 
 
 def enabled() -> bool:
@@ -120,21 +138,71 @@ def reset_trace() -> Trace:
     return _tls.trace
 
 
+def _on_compile(event: str, duration: float, **kwargs) -> None:
+    """``jax.monitoring`` listener: one ``compile`` span per event.
+
+    The span ends now and lasts ``duration``; its attrs are the event and
+    the ``fun_name`` JAX passes on trace, lowering and compile. It is a
+    child of the innermost open span of the reporting thread; a compile
+    outside every span belongs to no phase of the program and becomes an
+    ``event`` on the trace instead. An event reported inside another (a
+    nested jit traced within its caller, the cache load within a backend
+    compile) arrives first; when the enclosing one arrives, the earlier
+    spans it covers become events on it, so ``compile`` spans never
+    overlap and their durations add up to the time spent compiling.
+    """
+    if not _cfg["enabled"] or event not in COMPILE_EVENTS:
+        return
+    t_end = time.perf_counter()
+    attrs = {"event": event.rsplit("/", 1)[-1]}
+    if "fun_name" in kwargs:
+        attrs["fun_name"] = kwargs["fun_name"]
+    stack = _stack()
+    if not stack:
+        tr = current_trace()
+        tr.events.append({"name": "compile", "t": t_end - tr.t0,
+                          "attrs": dict(attrs, dur_s=duration)})
+        return
+    sp = Span("compile", attrs)
+    sp.t_start, sp.t_end = t_end - duration, t_end
+    siblings = stack[-1].children
+    while (siblings and siblings[-1].name == "compile"
+           and siblings[-1].t_start + siblings[-1].t_end >= 2 * sp.t_start):
+        inner = siblings.pop()
+        sp.events.insert(0, {"name": "compile",
+                             "t": inner.t_end - current_trace().t0,
+                             "attrs": dict(inner.attrs,
+                                           dur_s=inner.duration_s)})
+    siblings.append(sp)
+
+
+def _listen() -> None:
+    """Register :func:`_on_compile` with ``jax.monitoring``, once."""
+    _cfg["listening"] = True
+    import jax
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
 class _ActiveSpan:
     """Context manager yielded by :func:`span` when obs is enabled."""
 
-    __slots__ = ("_span", "_fenced")
+    __slots__ = ("_span", "_fenced", "_annotation")
 
     def __init__(self, name: str, attrs: dict):
         self._span = Span(name, attrs)
         self._fenced: list | None = None
+        self._annotation = None
 
     def __enter__(self) -> "_ActiveSpan":
+        import jax
         stack = _stack()
         parent = stack[-1] if stack else None
         (parent.children if parent is not None
          else current_trace().roots).append(self._span)
         stack.append(self._span)
+        self._annotation = jax.profiler.TraceAnnotation(
+            "obs." + self._span.name)
+        self._annotation.__enter__()
         self._span.t_start = time.perf_counter()
         return self
 
@@ -145,6 +213,7 @@ class _ActiveSpan:
             jax.block_until_ready(self._fenced)
             self._fenced = None
         sp.t_end = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             sp.attrs.setdefault("error", exc_type.__name__)
         stack = _stack()
@@ -199,6 +268,8 @@ def span(name: str, **attrs):
     """Open a span named ``name`` (no-op singleton when obs is disabled)."""
     if not _cfg["enabled"]:
         return _NOOP
+    if not _cfg["listening"]:
+        _listen()
     return _ActiveSpan(name, attrs)
 
 

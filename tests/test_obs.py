@@ -332,25 +332,155 @@ def _chunks(n_chunks=4, rows=32, cols=64, empty_at=()):
     return out
 
 
+def _tiny_fit_args():
+    import jax.numpy as jnp
+    from repro.core.lamc import LAMCConfig
+    a = jnp.asarray(np.random.default_rng(0).standard_normal((32, 32)),
+                    jnp.float32)
+    cfg = LAMCConfig(n_row_clusters=2, n_col_clusters=2, svd_iters=2,
+                     kmeans_iters=2, merge_kmeans_iters=2,
+                     merge_restarts=1, signature_dim=8)
+    return a, cfg
+
+
+#: the device phases every instruction of the fit program falls under
+FIT_PHASES = ("extract", "atom/normalize", "atom/svd", "atom/kmeans",
+              "signatures", "merge")
+
+
 class TestLamcTrace:
     def test_span_tree_and_plan_attrs(self, obs_on):
-        import jax.numpy as jnp
-        from repro.core.lamc import LAMCConfig, lamc_cocluster
+        from repro.core.lamc import lamc_cocluster
         tr = obs_on
-        a = jnp.asarray(np.random.default_rng(0).standard_normal((32, 32)),
-                        jnp.float32)
-        cfg = LAMCConfig(n_row_clusters=2, n_col_clusters=2, svd_iters=2,
-                         kmeans_iters=2, merge_kmeans_iters=2,
-                         merge_restarts=1, signature_dim=8)
+        a, cfg = _tiny_fit_args()
         lamc_cocluster(a, cfg)
         root = tr.find("lamc")[0]
         names = [c.name for c in root.children]
-        assert names == ["plan", "pipeline", "finalize"]
+        assert names == ["plan", "dispatch", "wait", "finalize"]
         for key in ("m", "n", "phi", "psi", "t_p", "spmm_route", "density"):
             assert key in root.attrs, f"missing plan attr {key}"
         assert root.attrs["rows"] == 32
-        pipeline = tr.find("pipeline")[0]
-        assert pipeline.attrs["phases"] == "partition/extract->atom->merge"
+        assert not tr.find("pipeline")
+        # the host's phases tile the fit in order, inside the root
+        kids = root.children
+        assert all(x.t_end <= y.t_start for x, y in zip(kids, kids[1:]))
+        assert kids[0].t_start >= root.t_start and kids[-1].t_end <= root.t_end
+
+    def test_spans_are_on_the_profiler_clock(self, obs_on, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+        from repro.core.lamc import lamc_cocluster
+        a, cfg = _tiny_fit_args()
+        lamc_cocluster(a, cfg)          # compile outside the trace
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            lamc_cocluster(a, cfg)
+        finally:
+            jax.profiler.stop_trace()
+        found = {}
+        for path in tmp_path.rglob("*.xplane.pb"):
+            for plane in ProfileData.from_file(str(path)).planes:
+                if not plane.name.startswith("/host:"):
+                    continue
+                for line in plane.lines:
+                    for e in line.events:
+                        if e.name.startswith("obs."):
+                            found.setdefault(e.name, (
+                                e.start_ns, e.start_ns + e.duration_ns))
+        assert {"obs.lamc", "obs.plan", "obs.dispatch", "obs.wait",
+                "obs.finalize"} <= set(found)
+        root = found["obs.lamc"]
+        for name in ("obs.plan", "obs.dispatch", "obs.wait", "obs.finalize"):
+            s, e = found[name]
+            assert root[0] <= s <= e <= root[1], name
+        assert found["obs.dispatch"][1] <= found["obs.wait"][0]
+
+    def test_device_phases_are_named_in_the_program(self):
+        import re
+
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
+        from repro.core import partition
+        from repro.core.lamc import _lamc_jit
+        a, cfg = _tiny_fit_args()
+        plan = partition.PartitionPlan(
+            n_rows=32, n_cols=32, m=2, n=2, phi=16, psi=16, t_p=2, seed=0,
+            detection_p=0.9)
+        # the persistent cache's key leaves op_name out: an entry written
+        # by an older program would bring that program's names with it
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            text = _lamc_jit.lower(a, cfg, plan).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+        # instructions of the program's own computations carry its name
+        # stack; the scalar bodies a reduce or sort applies carry only the
+        # primitive's name, and belong to the instruction that applies them
+        names = re.findall(r'op_name="(jit\(_lamc_jit\)/[^"]*)"', text)
+        assert len(names) > 100
+        hits = {p: 0 for p in FIT_PHASES}
+        under_one = 0
+        for n in names:
+            phases = [p for p in FIT_PHASES if f"/{p}/" in n + "/"]
+            for p in phases:
+                hits[p] += 1
+            under_one += len(phases) == 1
+        assert all(hits.values()), hits
+        assert under_one >= 0.95 * len(names), (under_one, len(names))
+
+    def test_compile_span_on_retrace_not_on_cache_hit(self, obs_on):
+        import jax
+        import jax.numpy as jnp
+        tr = obs_on
+
+        @jax.jit
+        def twice(x):
+            return 2.0 * x
+
+        def compiles(n):
+            with obs.span("call") as sp:
+                sp.fence(twice(jnp.ones((n,), jnp.float32)))
+            return [c for c in tr.roots[-1].children if c.name == "compile"]
+
+        first = compiles(5)
+        assert first, "the first call traces and compiles"
+        assert {c.attrs["fun_name"] for c in first} >= {"twice"}
+        assert compiles(5) == []            # cache hit: nothing recorded
+        again = compiles(7)                 # a new shape retraces
+        assert any(c.attrs["event"] == "jaxpr_trace_duration"
+                   for c in again)
+        # compile spans never overlap, and each lies inside its parent
+        parent = tr.roots[-1]
+        for x, y in zip(again, again[1:]):
+            assert x.t_end <= y.t_start + 1e-4
+        assert all(parent.t_start - 1e-4 <= c.t_start <= c.t_end
+                   <= parent.t_end for c in again)
+
+    def test_compile_outside_spans_is_an_event(self, obs_on):
+        import jax
+        import jax.numpy as jnp
+        tr = obs_on
+        jax.jit(lambda x: x + 3.0)(jnp.ones((3,)))
+        assert tr.roots == []
+        assert any(e["name"] == "compile" for e in tr.events)
+
+    def test_distributed_span_tree(self, obs_on):
+        import jax
+        from repro.core import partition
+        from repro.core.distributed import distributed_lamc
+        tr = obs_on
+        a, cfg = _tiny_fit_args()
+        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        plan = partition.PartitionPlan(
+            n_rows=32, n_cols=32, m=1, n=1, phi=32, psi=32, t_p=1, seed=0,
+            detection_p=0.9)
+        distributed_lamc(mesh, a, cfg, plan)
+        root = tr.find("distributed_lamc")[0]
+        assert [c.name for c in root.children] == [
+            "build_step", "dispatch", "wait", "finalize"]
 
 
 class TestKernelDispatch:
