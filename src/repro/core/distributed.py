@@ -5,7 +5,8 @@ Phase map (DESIGN.md §2):
   1. **Block scatter** (jit + GSPMD): ``extract_blocks`` gathers the
      permuted row/col groups out of the mesh-sharded data matrix. XLA emits
      the all-to-all; this is the only phase that moves matrix data, and it
-     moves each element exactly once per resample.
+     moves each element exactly once per resample. A whole-matrix plan
+     gathers nothing (``partition.whole_matrix``).
 
   2. **Per-block co-clustering** (shard_map): every device owns
      ``m*n / n_devices`` blocks and runs the atom co-clusterer *locally* —
@@ -386,7 +387,9 @@ def distributed_lamc(mesh: Mesh, a: jax.Array, cfg: LAMCConfig,
                   block_axes="/".join(block_axes),
                   resample_axis=resample_axis or "",
                   m=plan.m, n=plan.n, phi=plan.phi, psi=plan.psi,
-                  t_p=plan.t_p, spmm_route=plan.spmm_route):
+                  t_p=plan.t_p, spmm_route=plan.spmm_route,
+                  extract=partition.extraction(
+                      plan, cfg.input_format == "bcoo")):
         with obs.span("build_step"):
             step, in_sh, out_sh = lamc_step_fn(cfg, plan, mesh, block_axes,
                                                resample_axis=resample_axis)

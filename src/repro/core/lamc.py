@@ -5,7 +5,8 @@ multi-device version (``core.distributed``) reuses the same pieces under
 ``shard_map``; this module is its oracle in tests.
 
 Per resample ``t``:
-  1. ``partition.extract_blocks`` gathers the (m*n, phi, psi) block stack.
+  1. ``partition.extract_blocks`` gathers the (m*n, phi, psi) block stack
+     (a whole-matrix plan's is ``A`` itself, ``partition.whole_matrix``).
   2. The atom co-clusterer (SCC or NMTF) runs *vmapped* over the stack —
      on real hardware this is the embarrassingly parallel phase.
   3. Atom signatures are computed in the shared projection space.
@@ -152,43 +153,32 @@ def run_resample(a, plan, cfg: LAMCConfig, anchor_rows, anchor_cols, t,
     (``cfg.input_format``); the block stack and anchor slivers the atom
     phase consumes are identical either way.
 
-    ``operator`` (single-block plans only): a prepared sparse operand of
-    the whole matrix (``sparse.prepare_operator``). The atom then runs
-    SCC directly on it — SpMM subspace iteration, O(nnz)/O(occupied
-    tiles) per product — and the ``M x N`` block is never densified. The
-    per-resample row/col permutation is skipped (with one block it only
-    reorders points *within* the block, which block membership ignores),
-    so labels can differ from the densify path by k-means seeding order.
+    ``operator`` (whole-matrix plans only, ``partition.whole_matrix``): a
+    prepared sparse operand of the whole matrix
+    (``sparse.prepare_operator``). The atom then runs SCC directly on it —
+    SpMM subspace iteration, O(nnz)/O(occupied tiles) per product — and
+    the ``M x N`` block is never densified.
     """
     b = plan.blocks_per_resample
-    if operator is not None:
-        assert b == 1, "operator path requires a single-block plan"
-        with jax.named_scope("atom/svd"):
-            key_b = jax.random.fold_in(
-                jax.random.fold_in(jax.random.key(plan.seed + 1), t), 0)
-        res = spectral.scc(
-            key_b, operator, cfg.atom_k, cfg.atom_d,
-            svd_iters=cfg.svd_iters, kmeans_iters=cfg.kmeans_iters,
-            assign_impl=cfg.assign_impl, svd_method=cfg.svd_method,
-            qr_method=cfg.qr_method,
-        )
-        row_labels = res.row_labels[None]                  # (1, phi)
-        col_labels = res.col_labels[None]                  # (1, psi)
-        with jax.named_scope("extract"):
-            row_idx = jnp.arange(plan.n_rows, dtype=jnp.int32).reshape(
-                plan.m, plan.phi)
-            col_idx = jnp.arange(plan.n_cols, dtype=jnp.int32).reshape(
-                plan.n, plan.psi)
-    else:
-        extract = (partition.extract_blocks_sparse
-                   if cfg.input_format == "bcoo" else partition.extract_blocks)
-        with jax.named_scope("extract"):
+    with jax.named_scope("extract"):
+        if operator is None:
+            extract = (partition.extract_blocks_sparse
+                       if cfg.input_format == "bcoo"
+                       else partition.extract_blocks)
             blocks, row_idx, col_idx = extract(a, plan, t)
-        with jax.named_scope("atom/svd"):
-            keys = jax.vmap(
-                lambda i: jax.random.fold_in(jax.random.fold_in(jax.random.key(plan.seed + 1), t), i)
-            )(jnp.arange(b))
+        else:
+            assert partition.whole_matrix(plan), \
+                "operator path requires a whole-matrix plan"
+            row_idx, col_idx = partition.resample_indices(plan, t)
+    with jax.named_scope("atom/svd"):
+        keys = jax.vmap(
+            lambda i: jax.random.fold_in(jax.random.fold_in(jax.random.key(plan.seed + 1), t), i)
+        )(jnp.arange(b))
+    if operator is None:
         row_labels, col_labels = jax.vmap(_atom_fn(cfg))(keys, blocks)  # (B,phi),(B,psi)
+    else:
+        row_labels, col_labels = (
+            lab[None] for lab in _atom_fn(cfg)(keys[0], operator))  # (1,phi),(1,psi)
 
     with jax.named_scope("signatures"):
         # anchor features: every block's points restricted to the shared anchors
@@ -325,8 +315,7 @@ def lamc_cocluster(a, cfg: LAMCConfig,
             # knob says. The shared resolver keeps this decision identical to
             # the plan search's pricing/surfacing — what runs is what was
             # priced.
-            single = (plan.blocks_per_resample == 1 and cfg.atom == "scc"
-                      and plan.phi == plan.n_rows and plan.psi == plan.n_cols)
+            single = partition.whole_matrix(plan) and cfg.atom == "scc"
             route = probability.resolve_spmm_route(
                 cfg.spmm_impl, density, float(plan.phi) * plan.psi,
                 single=single, svd_method=cfg.svd_method)
@@ -344,6 +333,8 @@ def lamc_cocluster(a, cfg: LAMCConfig,
         # Resolved-plan attributes on the root span: what actually ran.
         root.set(m=plan.m, n=plan.n, phi=plan.phi, psi=plan.psi,
                  t_p=plan.t_p, spmm_route=plan.spmm_route,
+                 extract=partition.extraction(
+                     plan, cfg.input_format == "bcoo"),
                  density=round(float(density), 6))
         if block_mask is not None:
             block_mask = jnp.asarray(block_mask, dtype=bool)

@@ -11,6 +11,16 @@ Rows/cols that do not fit the uniform grid (``M mod m*phi``) are simply left
 out of that resample; across ``T_p`` random resamples every index is covered
 with overwhelming probability, and the Theorem-1 budget already accounts for
 per-resample misses. ``coverage_probability`` quantifies it.
+
+**Whole-matrix plans** (one block per resample, ``phi == M`` and
+``psi == N``; ``whole_matrix``) take the identity instead of a random
+permutation: the one block is ``A`` itself, and a permutation would only
+reorder points *within* it, which block membership ignores. Extraction
+is then no gather and no scatter — ``extract_blocks`` hands over ``A``
+unchanged — and every entry point (``lamc``'s dense, BCOO and sparse-operator
+routes, ``distributed``) follows this one rule. The atom sees the points
+in stored order, so k-means seeding, and only that, differs from a
+permuted block's.
 """
 
 from __future__ import annotations
@@ -22,8 +32,9 @@ import jax.numpy as jnp
 
 from . import probability
 
-__all__ = ["PartitionPlan", "make_plan", "resample_indices", "extract_blocks",
-           "extract_blocks_sparse", "coverage_probability"]
+__all__ = ["PartitionPlan", "make_plan", "whole_matrix", "extraction",
+           "resample_indices", "extract_blocks", "extract_blocks_sparse",
+           "coverage_probability"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -133,13 +144,31 @@ def coverage_probability(plan: PartitionPlan, axis: str | None = None) -> float:
     return min(row_cov, col_cov)
 
 
+def whole_matrix(plan: PartitionPlan) -> bool:
+    """True when a resample's one block is the whole matrix (module doc)."""
+    return (plan.blocks_per_resample == 1 and plan.phi == plan.n_rows
+            and plan.psi == plan.n_cols)
+
+
+def extraction(plan: PartitionPlan, sparse: bool) -> str:
+    """Which extraction a resample runs: ``"whole"`` (none, the matrix
+    itself), ``"scatter"`` (``extract_blocks_sparse``) or ``"gather"``."""
+    if whole_matrix(plan):
+        return "whole"
+    return "scatter" if sparse else "gather"
+
+
 def resample_indices(plan: PartitionPlan, resample: jax.Array | int):
     """Row/col index groups for one resample.
 
     Returns ``(row_idx, col_idx)`` of shapes ``(m, phi)`` / ``(n, psi)``:
     ``row_idx[i]`` are the global row ids landing in block-row ``i``.
     Deterministic in ``(plan.seed, resample)`` — re-derivable anywhere.
+    A whole-matrix plan's maps are the identity, ``arange`` of each axis.
     """
+    if whole_matrix(plan):
+        return (jnp.arange(plan.n_rows, dtype=jnp.int32)[None],
+                jnp.arange(plan.n_cols, dtype=jnp.int32)[None])
     key = jax.random.fold_in(jax.random.key(plan.seed), resample)
     krow, kcol = jax.random.split(key)
     row_perm = jax.random.permutation(krow, plan.n_rows)[: plan.rows_used]
@@ -153,9 +182,12 @@ def extract_blocks(a: jax.Array, plan: PartitionPlan, resample: jax.Array | int)
     """Extract the ``(m*n, phi, psi)`` block stack for one resample.
 
     Also returns the index maps so labels can be scattered back:
-    ``blocks[i * n + j] == a[row_idx[i]][:, col_idx[j]]``.
+    ``blocks[i * n + j] == a[row_idx[i]][:, col_idx[j]]``. A whole-matrix
+    plan's stack is ``a[None]``, with no gather.
     """
     row_idx, col_idx = resample_indices(plan, resample)
+    if whole_matrix(plan):
+        return a[None], row_idx, col_idx
     rows, cols = row_idx.reshape(-1), col_idx.reshape(-1)
     # Two gathers; the first one materializes an intermediate whose size
     # depends on order — (rows_used, N) rows-first vs (M, cols_used)
@@ -190,12 +222,15 @@ def extract_blocks_sparse(a, plan: PartitionPlan, resample: jax.Array | int):
 
     Bit-exact vs ``extract_blocks`` on the densified input: each block
     cell receives exactly one stored value or stays zero (BCOO indices
-    are unique), so there is no summation-order drift.
+    are unique), so there is no summation-order drift. A whole-matrix
+    plan's stack is the densified matrix, with no permutation.
     """
     from . import sparse as _sparse  # local: keep partition importable sans jax.experimental
 
     _sparse.validate_bcoo(a)
     row_idx, col_idx = resample_indices(plan, resample)
+    if whole_matrix(plan):
+        return a.todense()[None], row_idx, col_idx
     inv_row = jnp.full((plan.n_rows,), plan.rows_used, jnp.int32).at[
         row_idx.reshape(-1)].set(jnp.arange(plan.rows_used, dtype=jnp.int32))
     inv_col = jnp.full((plan.n_cols,), plan.cols_used, jnp.int32).at[

@@ -118,3 +118,104 @@ class TestBaselines:
         s = cocluster_scores(np.array(res.row_labels), np.array(res.col_labels),
                              planted.row_labels, planted.col_labels)
         assert s["nmi"] > 0.6, s
+
+
+def _tiny(rows=64, cols=48):
+    a = jnp.asarray(np.random.default_rng(0).standard_normal((rows, cols)),
+                    jnp.float32)
+    cfg = LAMCConfig(n_row_clusters=2, n_col_clusters=2, svd_iters=2,
+                     kmeans_iters=2, merge_kmeans_iters=2, merge_restarts=1,
+                     signature_dim=8)
+    return a, cfg
+
+
+class TestWholeMatrixExtraction:
+    """A plan whose one block is the whole matrix extracts nothing
+    (``partition.whole_matrix``); every other plan gathers as before."""
+
+    @pytest.mark.parametrize("m,n,phi,psi,gathers", [
+        (1, 1, 64, 48, False),    # whole matrix
+        (1, 1, 48, 48, True),     # 1 x 1, rows subsampled
+        (2, 2, 32, 24, True),     # multi-block
+    ])
+    def test_extract_phase_gathers_only_when_partitioning(
+            self, m, n, phi, psi, gathers):
+        import re
+
+        from jax.experimental.compilation_cache import compilation_cache
+        from repro.core.lamc import _lamc_jit
+        a, cfg = _tiny()
+        plan = PartitionPlan(64, 48, m=m, n=n, phi=phi, psi=psi, t_p=1,
+                             seed=0)
+        # the persistent cache's key leaves op_name out (tests/test_obs.py)
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            text = _lamc_jit.lower(a, cfg, plan).compile().as_text()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            compilation_cache.reset_cache()
+        ops = {re.search(r"\s(\S+?)\(", line.split(" = ", 1)[1]).group(1)
+               for line in text.splitlines()
+               if "/extract/" in line and " = " in line}
+        assert ("gather" in ops) == gathers, ops
+        if not gathers:
+            assert not ops & {"sort", "scatter", "transpose", "copy"}, ops
+
+    @pytest.mark.parametrize("fmt,phi,psi,impl,kind", [
+        ("dense", 64, 48, "auto", "whole"),
+        ("dense", 48, 48, "auto", "gather"),
+        ("dense", 32, 24, "auto", "gather"),
+        ("bcoo", 64, 48, "dense", "whole"),
+        ("bcoo", 64, 48, "dual_ell", "whole"),
+        ("bcoo", 32, 24, "auto", "scatter"),
+    ])
+    def test_root_span_names_the_extraction(self, fmt, phi, psi, impl, kind):
+        import dataclasses
+
+        from repro import obs
+        from repro.data import to_bcoo
+        a, cfg = _tiny()
+        cfg = dataclasses.replace(cfg, input_format=fmt, spmm_impl=impl)
+        if fmt == "bcoo":    # sparse, so a pinned sparse route is kept
+            keep = np.random.default_rng(1).random(a.shape) < 0.3
+            a = to_bcoo(np.where(keep, np.asarray(a), 0.0))
+        plan = PartitionPlan(64, 48, m=64 // phi, n=48 // psi, phi=phi,
+                             psi=psi, t_p=1, seed=0)
+        was = obs.enabled()
+        obs.configure(enabled=True)
+        tr = obs.reset_trace()
+        try:
+            out = lamc_cocluster(a, cfg, plan=plan)
+            root = tr.find("lamc")[0]
+        finally:
+            obs.configure(enabled=was)
+            obs.reset_trace()
+        assert root.attrs["extract"] == kind
+        if impl == "dual_ell":
+            assert out.plan.spmm_route == "dual_ell"
+
+    @pytest.mark.parametrize("data_seed", [0, 1, 2])
+    def test_whole_matrix_fit_is_the_reference_atom(self, data_seed):
+        """With one whole-matrix block and T_p = 1, the fit is the plain
+        SCC atom on ``A`` under the block's key (``baselines.scc_full``):
+        the same labels, so the same recovery of the planted truth."""
+        data = planted_cocluster_matrix(np.random.default_rng(data_seed),
+                                        240, 200, k=4, d=4, signal=8.0,
+                                        noise=0.2)
+        a = jnp.asarray(data.matrix)
+        plan = PartitionPlan(240, 200, m=1, n=1, phi=240, psi=200, t_p=1,
+                             seed=0)
+        out = lamc_cocluster(a, LAMCConfig(n_row_clusters=4, n_col_clusters=4),
+                             plan=plan)
+        key = jax.random.fold_in(                   # resample 0, block 0
+            jax.random.fold_in(jax.random.key(plan.seed + 1), 0), 0)
+        ref = scc_full(key, a, 4)
+        from repro.core.metrics import nmi
+        for got, want, truth in (
+                (out.row_labels, ref.row_labels, data.row_labels),
+                (out.col_labels, ref.col_labels, data.col_labels)):
+            got, want = np.asarray(got), np.asarray(want)
+            assert nmi(got, want) > 0.999
+            assert nmi(got, truth) == pytest.approx(nmi(want, truth))

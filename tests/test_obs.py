@@ -357,7 +357,8 @@ class TestLamcTrace:
         root = tr.find("lamc")[0]
         names = [c.name for c in root.children]
         assert names == ["plan", "dispatch", "wait", "finalize"]
-        for key in ("m", "n", "phi", "psi", "t_p", "spmm_route", "density"):
+        for key in ("m", "n", "phi", "psi", "t_p", "spmm_route", "extract",
+                    "density"):
             assert key in root.attrs, f"missing plan attr {key}"
         assert root.attrs["rows"] == 32
         assert not tr.find("pipeline")
@@ -481,6 +482,7 @@ class TestLamcTrace:
         root = tr.find("distributed_lamc")[0]
         assert [c.name for c in root.children] == [
             "build_step", "dispatch", "wait", "finalize"]
+        assert root.attrs["extract"] == "whole"
 
 
 class TestKernelDispatch:

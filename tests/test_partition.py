@@ -42,6 +42,28 @@ class TestResampleIndices:
         f = jax.jit(lambda t: partition.resample_indices(plan, t)[0])
         assert f(jnp.int32(1)).shape == (3, 40)
 
+    @pytest.mark.parametrize("m,n,phi,psi,whole", [
+        (1, 1, 120, 90, True),     # the one block is the whole matrix
+        (1, 1, 100, 90, False),    # 1 x 1, but rows subsampled
+        (3, 3, 40, 30, False),     # multi-block
+    ])
+    def test_index_maps_follow_plan_shape(self, m, n, phi, psi, whole):
+        """A whole-matrix plan's maps are the identity in every resample;
+        any other plan's are a random permutation of the used rows/cols."""
+        plan = partition.PartitionPlan(120, 90, m=m, n=n, phi=phi, psi=psi,
+                                       t_p=2, seed=3)
+        assert partition.whole_matrix(plan) == whole
+        for t in (0, 1):
+            row_idx, col_idx = (np.array(x) for x in
+                                partition.resample_indices(plan, t))
+            assert row_idx.shape == (m, phi) and col_idx.shape == (n, psi)
+            rows, cols = row_idx.reshape(-1), col_idx.reshape(-1)
+            assert len(np.unique(rows)) == plan.rows_used
+            assert len(np.unique(cols)) == plan.cols_used
+            iota = (np.array_equal(rows, np.arange(plan.rows_used))
+                    and np.array_equal(cols, np.arange(plan.cols_used)))
+            assert iota == whole
+
 
 class TestExtractBlocks:
     def test_block_content_matches_indices(self):
@@ -70,22 +92,27 @@ class TestExtractBlocks:
         vals = np.sort(np.array(blocks).ravel())
         np.testing.assert_array_equal(vals, np.arange(M * N, dtype=np.float32))
 
-    @pytest.mark.parametrize("M,N,phi,psi", [
-        (40, 400, 15, 20),   # wide: cols-first gather is cheaper
-        (400, 40, 20, 15),   # tall: rows-first gather is cheaper
+    @pytest.mark.parametrize("M,N,m,n,phi,psi", [
+        (40, 400, 2, 2, 15, 20),    # wide: cols-first gather is cheaper
+        (400, 40, 2, 2, 20, 15),    # tall: rows-first gather is cheaper
+        (400, 40, 1, 1, 300, 40),   # 1 x 1 subsampling: still gathers
+        (40, 400, 1, 1, 40, 400),   # whole matrix: no gather at all
     ])
-    def test_gather_order_is_content_invariant(self, M, N, phi, psi):
-        """Cheaper-axis-first gather must produce the exact same blocks."""
-        plan = partition.PartitionPlan(M, N, m=2, n=2, phi=phi, psi=psi,
+    def test_gather_order_is_content_invariant(self, M, N, m, n, phi, psi):
+        """Cheaper-axis-first gather must produce the exact same blocks;
+        a whole-matrix plan's one block is ``a`` itself."""
+        plan = partition.PartitionPlan(M, N, m=m, n=n, phi=phi, psi=psi,
                                        t_p=1, seed=5)
         a = jnp.asarray(np.random.default_rng(0).normal(size=(M, N)).astype(np.float32))
         blocks, row_idx, col_idx = partition.extract_blocks(a, plan, 0)
         rows = np.array(row_idx).reshape(-1)
         cols = np.array(col_idx).reshape(-1)
         expect = (np.array(a)[rows][:, cols]
-                  .reshape(2, phi, 2, psi).transpose(0, 2, 1, 3)
-                  .reshape(4, phi, psi))
+                  .reshape(m, phi, n, psi).transpose(0, 2, 1, 3)
+                  .reshape(m * n, phi, psi))
         np.testing.assert_array_equal(np.array(blocks), expect)
+        if partition.whole_matrix(plan):
+            np.testing.assert_array_equal(np.array(blocks[0]), np.array(a))
 
 
 class TestCoverage:

@@ -66,10 +66,12 @@ class TestBcooHelpers:
 
 
 class TestExtractBlocksSparse:
-    def test_exact_parity_full_grid(self, planted):
+    @pytest.mark.parametrize("m,n", [(2, 2), (1, 1)])  # (1, 1): whole matrix
+    def test_exact_parity_full_grid(self, planted, m, n):
         a = jnp.asarray(planted.matrix)
         a_sp = to_bcoo(planted.matrix)
-        plan = PartitionPlan(240, 200, m=2, n=2, phi=120, psi=100, t_p=2, seed=0)
+        plan = PartitionPlan(240, 200, m=m, n=n, phi=240 // m, psi=200 // n,
+                             t_p=2, seed=0)
         bd, ri, ci = partition.extract_blocks(a, plan, 1)
         bs, ri2, ci2 = partition.extract_blocks_sparse(a_sp, plan, 1)
         np.testing.assert_array_equal(np.asarray(bd), np.asarray(bs))
@@ -503,6 +505,35 @@ class TestSpmmImplLAMC:
         assert nmi(np.asarray(out_t.row_labels),
                    np.asarray(out_e.row_labels)) > 0.99
         assert nmi(np.asarray(out_t.row_labels), data.row_labels) > 0.5
+
+    @pytest.mark.parametrize("impl", ["dense", "dual_ell"])
+    def test_whole_matrix_plan_matches_dense_fit(self, impl):
+        """A whole-matrix plan runs the unpermuted atom on every route: the
+        densify route (``spmm_impl="dense"``) gives the dense path's labels
+        exactly, and the sparse operator agrees with them."""
+        rng = np.random.default_rng(1)
+        data = planted_cocluster_matrix(rng, 240, 200, k=4, d=4,
+                                        signal=8.0, noise=0.2, density=0.4)
+        plan = PartitionPlan(240, 200, m=1, n=1, phi=240, psi=200, t_p=1,
+                             seed=0)
+        base = dict(n_row_clusters=4, n_col_clusters=4,
+                    min_cocluster_rows=48, min_cocluster_cols=40)
+        out_d = lamc_cocluster(jnp.asarray(data.matrix), LAMCConfig(**base),
+                               plan=plan)
+        out_s = lamc_cocluster(
+            to_bcoo(data.matrix),
+            LAMCConfig(**base, input_format="bcoo", spmm_impl=impl),
+            plan=plan)
+        assert out_s.plan.spmm_route == impl
+        if impl == "dense":
+            np.testing.assert_array_equal(np.asarray(out_d.row_labels),
+                                          np.asarray(out_s.row_labels))
+            np.testing.assert_array_equal(np.asarray(out_d.col_labels),
+                                          np.asarray(out_s.col_labels))
+        for got, want in ((out_s.row_labels, out_d.row_labels),
+                          (out_s.col_labels, out_d.col_labels)):
+            assert nmi(np.asarray(got), np.asarray(want)) > 0.99
+        assert nmi(np.asarray(out_s.row_labels), data.row_labels) > 0.5
 
     def test_single_block_subsampling_plan_falls_back(self, planted):
         """A (1,1) plan with phi < M / psi < N subsamples per resample —
