@@ -7,7 +7,9 @@ For each cell, lowers the program its window drives at the cell's real
 sizes (``_lamc_jit`` for one chip, the ``distributed_lamc`` step on a
 described ``v5e:2x2`` for four) from shapes alone, compiles it for the
 chip and prints ``memory_analysis()`` per device and ``cost_analysis()``.
-What the chip's compiler refuses here costs no chip time. Code that asks
+A sparse configuration's operand is sized by the support it plants,
+drawn on the host (O(nnz) memory; the file stores no widths). What the
+chip's compiler refuses here costs no chip time. Code that asks
 ``jax.default_backend()`` still sees the CPU, so kernels that dispatch on
 the backend compile their portable branch.
 """
@@ -30,6 +32,48 @@ def topology(name: str = "v5e:2x2"):
     return topologies.get_topology_desc(platform="tpu", topology_name=name)
 
 
+def support_summary(config: dict) -> dict:
+    """``data.support_summary`` of the support that a sparse configuration
+    plants (drawn here, on the host's default device: it depends on
+    ``support_seed`` alone, so any ``--seed`` gives it)."""
+    import data
+
+    return data.support_summary(data.make(config, 0).a)
+
+
+def sparse_operand(route: str, shape: tuple, summary: dict, sds):
+    """The operand that ``route`` (``"dual_ell"`` or ``"tiled"``) builds
+    for a support of ``summary``, as ``sds(shape, dtype)`` leaves."""
+    import jax.numpy as jnp
+
+    from repro.core import sparse as core_sparse
+
+    m, n = shape
+    if route == "tiled":
+        from repro.kernels.spmm import BlockSparseMatrix
+
+        g = summary["tiles_128"]
+        return BlockSparseMatrix(sds((g, 128, 128), jnp.float32),
+                                 sds((g,), jnp.int32), sds((g,), jnp.int32),
+                                 sds((g,), jnp.int32), (m, n))
+    w_r, w_c = summary["row_width"], summary["col_width"]
+    return core_sparse.EllOperator(
+        row_vals=sds((m, w_r), jnp.float32),
+        row_cols=sds((m, w_r), jnp.int32),
+        col_vals=sds((n, w_c), jnp.float32),
+        col_rows=sds((n, w_c), jnp.int32))
+
+
+def operand_bytes(route: str, shape: tuple, summary: dict) -> int:
+    """Bytes of :func:`sparse_operand`'s arrays."""
+    import jax
+    import numpy as np
+
+    op = sparse_operand(route, shape, summary, jax.ShapeDtypeStruct)
+    return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in jax.tree.leaves(op))
+
+
 def fit_program(config: dict, topo, chips: int):
     """``(compiled, plan)`` of the fit program of ``config`` for ``topo``."""
     import jax
@@ -39,7 +83,6 @@ def fit_program(config: dict, topo, chips: int):
     from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
     from repro.core import LAMCConfig, lamc
-    from repro.core import sparse as core_sparse
     from repro.core.distributed import lamc_step_fn
     from repro.core.partition import make_plan
 
@@ -68,22 +111,9 @@ def fit_program(config: dict, topo, chips: int):
         compiled = lamc._lamc_jit.lower(sds((m, n), jnp.float32), cfg,
                                         plan).compile()
         return compiled, plan
-    sup = config["support"]
+    sup = support_summary(config)
     nnz = sup["nnz"]
-    if cfg.spmm_impl == "tiled":
-        from repro.kernels.spmm import BlockSparseMatrix
-
-        g = sup["tiles_128"]
-        op = BlockSparseMatrix(sds((g, 128, 128), jnp.float32),
-                               sds((g,), jnp.int32), sds((g,), jnp.int32),
-                               sds((g,), jnp.int32), (m, n))
-    else:
-        w_r, w_c = sup["row_width"], sup["col_width"]
-        op = core_sparse.EllOperator(
-            row_vals=sds((m, w_r), jnp.float32),
-            row_cols=sds((m, w_r), jnp.int32),
-            col_vals=sds((n, w_c), jnp.float32),
-            col_rows=sds((n, w_c), jnp.int32))
+    op = sparse_operand(cfg.spmm_impl, (m, n), sup, sds)
     plan = dataclasses.replace(plan, spmm_route=cfg.spmm_impl)
 
     def program(values, indices, op):

@@ -194,12 +194,38 @@ def _product(a, x, *, transpose, dtype):
     return jnp.matmul(am.T if transpose else am, x, precision=prec)
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "transpose", "dtype"))
-def _product_sparse(vals, idx, x, *, shape, transpose, dtype):
-    """:func:`_product` over the stored entries of a sparse matrix."""
+#: bytes of the ``(entries, p)`` float32 terms that a sparse product forms
+#: at once. The TPU pads ``p`` to 128 lanes, so a chunk takes ``128 / p``
+#: times this: at RCV1-v2's 60.8 M entries all at once would be 31 GB.
+PRODUCT_CHUNK_BYTES = 1 << 28
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("shape", "transpose", "dtype", "chunk"))
+def _product_sparse(vals, idx, x, *, shape, transpose, dtype, chunk):
+    """:func:`_product` over the stored entries of a sparse matrix, summed
+    ``chunk`` entries at a time, so that its terms never take more than
+    ``chunk * p`` elements."""
     src, dst = (idx[:, 0], idx[:, 1]) if transpose else (idx[:, 1], idx[:, 0])
-    terms = vals.astype(dtype)[:, None] * x.astype(dtype)[src]
-    return jax.ops.segment_sum(terms, dst, shape[1] if transpose else shape[0])
+    n_out = shape[1] if transpose else shape[0]
+    xd = x.astype(dtype)
+
+    def part(v, s, t):
+        return jax.ops.segment_sum(v.astype(dtype)[:, None] * xd[s], t, n_out)
+
+    nnz = vals.shape[0]
+    chunk = min(chunk, nnz)
+    full = nnz // chunk * chunk
+
+    def step(i, acc):
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk)
+        return acc + part(cut(vals), cut(src), cut(dst))
+
+    acc = jax.lax.fori_loop(0, nnz // chunk, step,
+                            jnp.zeros((n_out, x.shape[1]), dtype))
+    if full < nnz:
+        acc = acc + part(vals[full:], src[full:], dst[full:])
+    return acc
 
 
 def _orth(y: np.ndarray) -> np.ndarray:
@@ -228,12 +254,15 @@ def scc(a, k: int, d: int, seed, *, dtype=jnp.float32, iters: int = 8,
     Returns the row and column labels and the singular values.
     """
     m, n = a.shape
+    l = max(k, d).bit_length()
+    p = min(l + 1 + oversample, m, n)
     keep = jnp.asarray(np.arange(n) < int(round(keep_cols * n)))
     if _is_sparse(a):
         degrees = _abs_degrees_sparse(a.data, a.indices, keep, shape=(m, n),
                                       dtype=dtype)
+        chunk = PRODUCT_CHUNK_BYTES // (jnp.dtype(dtype).itemsize * p)
         product = functools.partial(_product_sparse, a.data, a.indices,
-                                    shape=(m, n))
+                                    shape=(m, n), chunk=max(chunk, 1))
     else:
         degrees = _abs_degrees(a, keep, dtype=dtype)
         product = functools.partial(_product, a)
@@ -251,9 +280,8 @@ def scc(a, k: int, d: int, seed, *, dtype=jnp.float32, iters: int = 8,
                     dtype=dtype)
         return scale_out[:, None] * np.asarray(jax.device_get(y), np.float64)
 
-    l = max(k, d).bit_length()
     rng = np.random.default_rng(seed)
-    x = _orth(rng.standard_normal((n, min(l + 1 + oversample, m, n))))
+    x = _orth(rng.standard_normal((n, p)))
     for _ in range(iters):
         x = _orth(prod(_orth(prod(x, False)), True))
     q = _orth(prod(x, False))                               # (M, p)

@@ -63,11 +63,14 @@ def tree(tmp_path_factory):
     ("tiny_sparse_fit", ALTER_LABELS, 1),
     ("tiny_dense_fit", HALF_LEFT_OUT, 1),
     ("tiny_dense_fit", ATOM_HALF_LEFT_OUT, 1),
+    ("tiny_doc_term_fit", ALTER_LABELS, 1),
+    ("tiny_doc_term_fit", ATOM_HALF_LEFT_OUT, 1),
     ("tiny_mesh_fit", HALF_LEFT_OUT, 4),
     ("tiny_mesh_fit", NO_EXCHANGE, 4),
     ("tiny_serve", ALTER_SCORES, 1),
 ], ids=["fit-answer-altered", "sparse-answer-altered", "fit-half-left-out",
-        "fit-atom-half-left-out",
+        "fit-atom-half-left-out", "doc-term-answer-altered",
+        "doc-term-atom-half-left-out",
         "mesh-half-left-out", "mesh-exchange-left-out", "serve-answer-altered"])
 def test_a_broken_timed_path_is_not_correct(tree, workload, patch, devices):
     rc, sound, err = tiny.run(tree, workload, devices=devices)
@@ -81,12 +84,11 @@ def test_a_broken_timed_path_is_not_correct(tree, workload, patch, devices):
 
 
 @pytest.mark.parametrize("workload", ["tiny_dense_fit", "tiny_sparse_fit",
-                                      "tiny_serve"])
+                                      "tiny_doc_term_fit", "tiny_serve"])
 def test_the_control_fails_and_the_program_passes(tree, workload):
     import json
 
-    config = tiny.CONFIGS["tiny_dense" if workload != "tiny_sparse_fit"
-                          else "tiny_sparse"]
+    config = tiny.CONFIGS[tiny.CELLS[workload][0]]
     limits = config["limits"]
     held = lambda nums: {n: v for n, v in nums.items() if n in limits}
     for r in tiny.control(tree, workload, [3, 4, 5]):

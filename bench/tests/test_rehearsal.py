@@ -31,8 +31,8 @@ def test_new_files_are_found_without_editing_existing_ones(tree):
 
 
 @pytest.mark.parametrize("workload,devices", [
-    ("tiny_dense_fit", 1), ("tiny_sparse_fit", 1), ("tiny_serve", 1),
-    ("tiny_mesh_fit", 4)])
+    ("tiny_dense_fit", 1), ("tiny_sparse_fit", 1), ("tiny_doc_term_fit", 1),
+    ("tiny_serve", 1), ("tiny_mesh_fit", 4)])
 def test_every_mix_runs_end_to_end(tree, workload, devices):
     rc, last, err = tiny.run(tree, workload, devices=devices)
     assert rc == 0, err[-3000:]
